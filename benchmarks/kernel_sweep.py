@@ -214,13 +214,13 @@ def sweep(
     import jax.numpy as jnp
 
     from repro import core
-    from repro.core.hardware import host_spec
+    from repro.core.hardware import device_spec
     from repro.core.measure import operand_shapes
     from repro.core.simulate import matmul_flops
     from repro.kernels import DEFAULT_BLOCK, should_interpret
     from repro.kernels.tiling import config_key, default_config
 
-    hw = host_spec()
+    hw = device_spec()
     mode = "interpret" if should_interpret() else "compiled"
     dt = jnp.dtype(dtype)
     rng = np.random.RandomState(0)

@@ -60,4 +60,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.common import enable_compile_cache
+
+    enable_compile_cache()
     raise SystemExit(main())

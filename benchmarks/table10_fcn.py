@@ -77,7 +77,7 @@ def table10(full: bool = False):
     section("Table X / Figs.7-8 — FCN training: always-NT vs MTNN (measured)")
     ds = measured_dataset(full)
     clf, rep = core.train_paper_model(ds)
-    mtnn = core.ModelPolicy(core.MTNNSelector(clf, hardware=core.host_spec()))
+    mtnn = core.ModelPolicy(core.MTNNSelector(clf, hardware=core.device_spec()))
     nt = core.FixedPolicy("XLA_NT")  # the CaffeNT arm
 
     out: Dict[str, Dict] = {}

@@ -20,8 +20,10 @@ from repro import core
 from repro.checkpoint import CheckpointManager
 from repro.core.engine import POLICY_SPEC_HELP
 from repro.core.faults import add_chaos_argument, chaos_scope
-from repro.models.fcn import FCNConfig, fcn_loss, init_fcn
-from repro.optim import adamw_init, adamw_update, clip_by_global_norm, warmup_cosine
+from repro.data import make_fcn_batch
+from repro.launch.steps import make_fcn_train_step
+from repro.models.fcn import FCNConfig, init_fcn
+from repro.optim import adamw_init, warmup_cosine
 
 
 def main():
@@ -68,7 +70,7 @@ def _run(args):
         ds = core.collect_measured(sizes=[64, 256, 1024], reps=2)
         clf, _ = core.train_paper_model(ds)
         policy = core.ModelPolicy(
-            core.MTNNSelector(clf, hardware=core.host_spec())
+            core.MTNNSelector(clf, hardware=core.device_spec())
         )
         print(f"[fcn] selector trained on {len(ds)} measured samples")
 
@@ -78,24 +80,14 @@ def _run(args):
     sched = warmup_cosine(args.lr, warmup=20, total=args.steps)
     ckpt = CheckpointManager(args.ckpt_dir, keep=2)
 
-    @jax.jit
-    def step_fn(params, opt, step, batch):
-        # dispatch decisions happen while tracing, inside this policy scope
-        with core.use_policy(policy):
-            (loss, _), grads = jax.value_and_grad(
-                lambda p: fcn_loss(p, batch), has_aux=True
-            )(params)
-        grads, gnorm = clip_by_global_norm(grads, 1.0)
-        params, opt = adamw_update(grads, opt, params, sched(step))
-        return params, opt, loss, gnorm
+    # dispatch decisions happen while tracing, inside the policy scope
+    step_fn = jax.jit(make_fcn_train_step(policy, sched))
 
     rng = np.random.RandomState(0)
     w_true = rng.randn(cfg.input_dim, 8).astype(np.float32)
     t_hist = []
     for step in range(args.steps):
-        x = rng.randn(args.batch, cfg.input_dim).astype(np.float32)
-        labels = (x @ w_true).argmax(-1) % cfg.output_dim  # learnable rule
-        batch = {"x": jnp.asarray(x), "labels": jnp.asarray(labels)}
+        batch = make_fcn_batch(rng, cfg, args.batch, w_true)
         t0 = time.perf_counter()
         params, opt, loss, gnorm = step_fn(params, opt, jnp.asarray(step), batch)
         loss.block_until_ready()

@@ -1,17 +1,3 @@
 """repro — supervised algorithm selection for NT matmuls, grown into a
 policy-dispatched jax/pallas serving + training stack."""
 
-# jax is optional at the package level so the jax-free tooling
-# (repro.analysis artifact/dispatch lint) runs on checkouts without the
-# accelerator stack; every compute module still imports jax directly.
-try:
-    import jax
-except ImportError:
-    jax = None
-
-# Sharding-invariant RNG: newer jax defaults this on; on older versions the
-# legacy threefry lowering can produce *different* random bits when an init
-# is jitted with out_shardings over a >1-device mesh (breaks the elastic-
-# restart and SPMD-equivalence guarantees).  Normalize it here.
-if jax is not None and hasattr(jax.config, "jax_threefry_partitionable"):
-    jax.config.update("jax_threefry_partitionable", True)

@@ -52,10 +52,6 @@ _ACCUM_PRIMS = {"add", "add_any", "sub", "mul", "dot_general"}
 
 def _subjaxprs(value):
     """Yield every Jaxpr reachable from one eqn param value."""
-    import jax
-
-    closed = getattr(jax.extend.core if hasattr(jax, "extend") else jax.core,
-                     "ClosedJaxpr", None)
     # duck-type: anything with .eqns is a jaxpr, anything with .jaxpr wraps one
     if hasattr(value, "eqns"):
         yield value
@@ -64,7 +60,6 @@ def _subjaxprs(value):
     elif isinstance(value, (tuple, list)):
         for v in value:
             yield from _subjaxprs(v)
-    del closed
 
 
 def _walk_jaxprs(jaxpr):

@@ -28,7 +28,7 @@ import numpy as np
 from . import simulate
 from .candidates import BINARY_PAIRS_BY_OP, CANDIDATES, PAPER_PAIR
 from .features import make_features
-from .hardware import SIMULATED_CHIPS, HardwareSpec, host_spec
+from .hardware import SIMULATED_CHIPS, HardwareSpec, device_spec
 
 __all__ = [
     "SelectionDataset",
@@ -176,12 +176,13 @@ def collect_measured(
     max_flops: float = 5e10,
     verbose: bool = False,
 ) -> SelectionDataset:
-    """Real wall-clock dataset on the current backend (host CPU here)."""
+    """Real wall-clock dataset on the current device, whose features are
+    its ``device_spec()``."""
     import jax
     import jax.numpy as jnp
 
     sizes = [2**i for i in range(5, 11)] if sizes is None else list(sizes)
-    hw = host_spec()
+    hw = device_spec()
     nt_fn = jax.jit(CANDIDATES[candidates[0]].fn)
     tnn_fn = jax.jit(CANDIDATES[candidates[1]].fn)
     key = jax.random.PRNGKey(0)
@@ -265,9 +266,9 @@ def dataset_from_measurements(
     op_pairs["NT"] = tuple(pair)
     for op, p in (pairs or {}).items():
         op_pairs[op] = tuple(p)
-    host = host_spec()
+    here = device_spec()
     specs = dict(SIMULATED_CHIPS)
-    specs[host.name] = host
+    specs[here.name] = here
     kept: List[Tuple[HardwareSpec, str, int, int, int, Dict[str, float]]] = []
     unknown_hw: Dict[str, int] = {}
     other_dtypes: Dict[str, int] = {}

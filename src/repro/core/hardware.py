@@ -25,6 +25,9 @@ __all__ = [
     "TPU_V4",
     "TPU_V5P",
     "SIMULATED_CHIPS",
+    "DEVICE_KINDS",
+    "device_spec",
+    "target_spec",
     "host_spec",
 ]
 
@@ -94,9 +97,50 @@ SIMULATED_CHIPS: Dict[str, HardwareSpec] = {
     c.name: c for c in (TPU_V5E, TPU_V4, TPU_V5P)
 }
 
+# ``device_kind`` as JAX reports it -> descriptor.  A TPU kind missing here
+# is an error (``device_spec``), never a silent stand-in.  Add a kind only
+# once a run on that chip has shown the string JAX reports for it.
+DEVICE_KINDS: Dict[str, HardwareSpec] = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def device_spec(device=None) -> HardwareSpec:
+    """Descriptor of the device JAX computes on (default: the first).
+
+    A TPU maps by its ``device_kind`` through ``DEVICE_KINDS`` and an
+    unknown kind raises; the CPU backend gets ``host_spec()``.  Measured
+    data (autotune caches, measured selector datasets) is keyed by this,
+    so timings are never filed under a chip they were not taken on."""
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return host_spec()
+    spec = DEVICE_KINDS.get(device.device_kind)
+    if spec is None:
+        raise ValueError(
+            f"no HardwareSpec for {device.platform} device_kind "
+            f"{device.device_kind!r}; add it to hardware.DEVICE_KINDS"
+        )
+    return spec
+
+
+def target_spec() -> HardwareSpec:
+    """The chip the analytic and learned policies model: the attached
+    device's descriptor, or ``TPU_V5E`` on the CPU backend — there the
+    kernels run in interpret mode as a rehearsal of the v5e path, and a
+    TPU cost model fed CPU peaks would describe neither machine."""
+    import jax
+
+    device = jax.devices()[0]
+    return TPU_V5E if device.platform == "cpu" else device_spec(device)
+
 
 def host_spec() -> HardwareSpec:
-    """Best-effort descriptor of the *current* host (for measured-CPU data)."""
+    """Best-effort descriptor of the host CPU, for data measured on the
+    CPU backend.  Its peaks are rough, not published numbers."""
     ncpu = os.cpu_count() or 1
     mem_gib = 16.0
     try:

@@ -16,10 +16,11 @@ measures-and-caches cold shapes; ``dataset_from_measurements``
 (core/dataset.py) turns a populated cache into a ``SelectionDataset`` so
 the paper's GBDT can be retrained from autotune-collected records.
 
-Measurement runs under ``jax.ensure_compile_time_eval()`` so it stays
-eager even when ``select()`` fires inside a ``jit`` trace (where dispatch
-normally happens); ``measurement_supported()`` reports whether that escape
-hatch exists so callers can fall back to the analytic model instead.
+``select()`` fires while a ``jit`` traces, where any JAX call would be
+staged rather than run.  So every sweep runs on a thread of its own
+(``run_outside_trace``), where nothing is traced: it compiles each
+candidate ahead of time for concrete operands and times the executable
+on the device (``bench_fn``).
 """
 
 from __future__ import annotations
@@ -40,7 +41,7 @@ from .candidates import (
     candidate_fits_memory,
     get_candidate,
 )
-from .hardware import HardwareSpec, host_spec
+from .hardware import HardwareSpec, device_spec
 from .opkey import check_op, shape_key
 
 __all__ = [
@@ -51,7 +52,7 @@ __all__ = [
     "measure_candidates",
     "measure_transpose_configs",
     "best_transpose_config",
-    "measurement_supported",
+    "run_outside_trace",
     "default_cache_path",
     "best_times",
     "top_configs_by_candidate",
@@ -425,59 +426,53 @@ def _move_aside_cache(path: str, reason: BaseException) -> None:
     )
 
 
-def _trace_state_clean() -> bool:
-    """True when no jax trace is active (eager context)."""
-    try:
-        from jax.core import trace_state_clean
+def run_outside_trace(fn, *args, **kw):
+    """Call ``fn(*args, **kw)`` on a fresh thread and return its result.
 
-        return bool(trace_state_clean())
-    except ImportError:
-        return True  # no introspection available: assume eager
+    Selection runs while a ``jit`` traces, and there every JAX call is
+    staged into the traced program: a timing taken in place measures
+    tracing, not the device.  JAX keeps its trace state per thread, so on
+    a new thread nothing is being traced — operands are concrete device
+    arrays and a compiled executable runs on the device.  The caller's
+    contextvars (fault rules, the policy scope) go along."""
+    import concurrent.futures
+    import contextvars
 
-
-def measurement_supported() -> bool:
-    """Whether eager wall-clock timing is possible right now.
-
-    Inside a trace, ``jax.ensure_compile_time_eval()`` is the escape hatch
-    that keeps measurement eager; without it (very old jax) measurement is
-    only safe when no trace is active.
-    """
-    import jax
-
-    return _trace_state_clean() or hasattr(jax, "ensure_compile_time_eval")
-
-
-def _eval_scope():
-    """Eager-execution scope for measurement: a no-op outside traces (where
-    plain jit works, Pallas included), ``ensure_compile_time_eval`` inside
-    one (the escape hatch that keeps timing off the traced program)."""
-    import jax
-
-    if not _trace_state_clean() and hasattr(jax, "ensure_compile_time_eval"):
-        return jax.ensure_compile_time_eval()
-    return contextlib.nullcontext()
+    ctx = contextvars.copy_context()
+    with concurrent.futures.ThreadPoolExecutor(
+        max_workers=1, thread_name_prefix="repro-measure"
+    ) as pool:
+        return pool.submit(ctx.run, fn, *args, **kw).result()
 
 
 def bench_fn(
     fn, *operands, reps: int = 3, warmup: int = 1, stat: str = "median"
 ) -> float:
-    """Warmup (incl. compile) then ``stat`` of ``reps`` wall-clock runs of
-    ``fn(*operands)`` — two operands for the GEMM ops, three (q, k, v)
-    for the attention subgraph op.
+    """Compile ``fn`` for ``operands`` ahead of time, run the executable
+    ``warmup`` times, then return the ``stat`` of ``reps`` wall-clock runs
+    — two operands for the GEMM ops, three (q, k, v) for the attention
+    subgraph op.  Compilation is never inside a timing.
 
     The one timing loop in the codebase: ``measure_candidates`` uses the
     median (robust to scheduler noise in small-rep autotuning),
     ``dataset.collect_measured`` the min (paper-style best-case).
+    Operands must be concrete arrays: tracers mean the caller is inside a
+    trace, where a timing would measure tracing (``run_outside_trace``).
     """
     import jax
 
-    jax.block_until_ready(fn(*operands))  # compile + first warmup
-    for _ in range(max(0, warmup - 1)):
-        jax.block_until_ready(fn(*operands))
+    if any(isinstance(x, jax.core.Tracer) for x in operands):
+        raise TypeError(
+            "bench_fn needs concrete operands, got tracers: call it outside "
+            "the jit trace (measure.run_outside_trace)"
+        )
+    compiled = jax.jit(fn).lower(*operands).compile()
+    for _ in range(max(1, warmup)):
+        jax.block_until_ready(compiled(*operands))
     ts = []
     for _ in range(reps):
         t0 = time.perf_counter()
-        jax.block_until_ready(fn(*operands))
+        jax.block_until_ready(compiled(*operands))
         ts.append(time.perf_counter() - t0)
     return float(statistics.median(ts) if stat == "median" else min(ts))
 
@@ -538,27 +533,33 @@ def measure_candidates(
     result may be empty.
 
     A pair that raises is retried up to ``retries`` more times with
-    exponential backoff (transient allocation/compile hiccups recover; a
-    pair that keeps failing is simply not a measurement).
-    ``KeyboardInterrupt``/``SystemExit`` always propagate.  When the
-    caller passes an ``attempts`` dict, the try count of every successful
-    measurement is recorded into it as ``{name: {config_key: n}}`` —
-    AutotunePolicy persists that beside the cache entry.
+    exponential backoff (transient allocation/compile hiccups recover); a
+    pair that keeps failing is not a measurement, and says so with a
+    warning.  ``KeyboardInterrupt``/``SystemExit`` always propagate.  When
+    the caller passes an ``attempts`` dict, the try count of every
+    successful measurement is recorded into it as ``{name: {config_key:
+    n}}`` — AutotunePolicy persists that beside the cache entry.
+
+    The sweep runs on its own thread (``run_outside_trace``), so a call
+    made while a ``jit`` traces — where selection happens — still times
+    compiled code executing on the device.
     """
     import functools
+    import warnings
 
     import jax
     import jax.numpy as jnp
 
     from repro.kernels.tiling import DEFAULT_CONFIG_KEY, config_key
 
-    hw = hardware or host_spec()
+    hw = hardware or device_spec()
     names = tuple(candidates or CANDIDATES)
     dt = jnp.dtype(dtype)
     dsize = dt.itemsize
     shapes = operand_shapes(op, m, n, k, g)
-    times: Dict[str, Dict[str, float]] = {}
-    with _eval_scope():
+
+    def sweep_all() -> Dict[str, Dict[str, float]]:
+        times: Dict[str, Dict[str, float]] = {}
         op_keys = jax.random.split(jax.random.PRNGKey(seed), len(shapes))
         operands = tuple(
             jax.random.normal(kk, s, dtype=dt)
@@ -593,25 +594,34 @@ def measure_candidates(
                     try:
                         faults.check_measure_fault(name, op)
                         entry[ck] = bench_fn(
-                            jax.jit(fn), *operands, reps=reps, warmup=warmup
+                            fn, *operands, reps=reps, warmup=warmup
                         )
                         entry_tries[ck] = n_try
                         break
                     except (KeyboardInterrupt, SystemExit):
                         raise  # user/runtime interrupts are never a retry
-                    except Exception:
-                        # a pair that cannot run here (kernel unsupported
-                        # under the eval trace, allocation failure, ...):
-                        # back off and retry a bounded number of times; a
-                        # persistent failure is simply not a measurement —
-                        # selection proceeds over those that ran
+                    except Exception as e:
+                        # a pair that cannot run here (compile refusal,
+                        # allocation failure, ...): back off and retry a
+                        # bounded number of times; a persistent failure is
+                        # not a measurement — selection proceeds over those
+                        # that ran
                         if n_try <= retries:
                             time.sleep(retry_backoff_s * (2 ** (n_try - 1)))
+                        else:
+                            warnings.warn(
+                                f"measurement of {name}@{ck} on {op} "
+                                f"g={g} {m}x{n}x{k} {dtype} failed "
+                                f"({type(e).__name__}: {e}); not timed",
+                                UserWarning,
+                            )
             if entry:
                 times[name] = entry
                 if attempts is not None:
                     attempts[name] = entry_tries
-    return times
+        return times
+
+    return run_outside_trace(sweep_all)
 
 
 def top_configs_by_candidate(
@@ -707,6 +717,8 @@ def measure_transpose_configs(
     kernel-default tiling, returning ``{config_key: seconds}``.  The
     transpose is the second stage of the TNN/TN candidates, so a tuned
     ``tblock`` feeds ``ops.matmul_tnn`` / ``ops.matmul_tn`` directly."""
+    import warnings
+
     import jax
     import jax.numpy as jnp
 
@@ -717,10 +729,11 @@ def measure_transpose_configs(
         transpose_config_space,
     )
 
-    hw = hardware or host_spec()
+    hw = hardware or device_spec()
     dt = jnp.dtype(dtype)
-    times: Dict[str, float] = {}
-    with _eval_scope():
+
+    def sweep_all() -> Dict[str, float]:
+        times: Dict[str, float] = {}
         b = jax.random.normal(jax.random.PRNGKey(seed), (rows, cols), dtype=dt)
         sweep = [(DEFAULT_CONFIG_KEY, None)] + [
             (config_key(cfg), cfg)
@@ -729,22 +742,22 @@ def measure_transpose_configs(
             )
         ]
         for ck, cfg in sweep:
-            fn = jax.jit(lambda x, _cfg=cfg: ops.transpose(x, block=_cfg))
             try:
-                jax.block_until_ready(fn(b))  # compile + first warmup
-                for _ in range(max(0, warmup - 1)):
-                    jax.block_until_ready(fn(b))
-                ts = []
-                for _ in range(reps):
-                    t0 = time.perf_counter()
-                    jax.block_until_ready(fn(b))
-                    ts.append(time.perf_counter() - t0)
-                times[ck] = float(statistics.median(ts))
+                times[ck] = bench_fn(
+                    lambda x, _cfg=cfg: ops.transpose(x, block=_cfg),
+                    b, reps=reps, warmup=warmup,
+                )
             except (KeyboardInterrupt, SystemExit):
                 raise  # user/runtime interrupts are never swallowed
-            except Exception:
-                continue  # an unrunnable tile is simply not a measurement
-    return times
+            except Exception as e:
+                warnings.warn(
+                    f"measurement of transpose@{ck} at {rows}x{cols} {dtype} "
+                    f"failed ({type(e).__name__}: {e}); not timed",
+                    UserWarning,
+                )
+        return times
+
+    return run_outside_trace(sweep_all)
 
 
 def best_transpose_config(

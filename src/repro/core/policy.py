@@ -35,8 +35,9 @@ The policy zoo:
   CascadePolicy   ordered preference list with OOM + distributed fallback
   AutotunePolicy  argmin of *on-device measurements* over the full
                   (candidate x config) space (core/measure.py);
-                  measures-and-caches cold shapes, analytic fallback when
-                  measurement is impossible (e.g. multi-device pjit)
+                  measures-and-caches cold shapes, announced analytic
+                  fallback when measurement is impossible (e.g.
+                  multi-device pjit)
 
 All selection runs at *trace* time under ``jit`` (JAX shapes are static),
 so every policy's compiled-step overhead is exactly zero — the paper's
@@ -48,6 +49,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import warnings
 from typing import (
     Dict,
     Iterator,
@@ -69,7 +71,7 @@ from .candidates import (
     current_platform,
     get_candidate,
 )
-from .hardware import TPU_V5E, HardwareSpec, host_spec
+from .hardware import HardwareSpec, device_spec, target_spec
 from .opkey import OPS, OpKey, check_op, coerce_key
 
 __all__ = [
@@ -135,7 +137,7 @@ class PolicyBase:
     ):
         from .selector import SelectorStats  # local: avoid import cycle
 
-        self.hardware = hardware or TPU_V5E
+        self.hardware = hardware or target_spec()
         self.distributed = distributed
         self.mem_budget_frac = mem_budget_frac
         self.stats = SelectorStats()
@@ -435,16 +437,19 @@ class AutotunePolicy(PolicyBase):
     ``select`` answers from a persistent ``MeasurementCache`` (warm hit);
     on a cold shape it measures every admissible candidate — tunable ones
     across their roofline-pruned config shortlist (``max_tile_configs``
-    wide) — right there at trace time (``core/measure.py`` keeps the
-    timing eager via ``ensure_compile_time_eval``), stores the result, and
-    persists the cache.  When measurement is disabled or impossible —
-    ``measure=False``, ``distributed=True`` (multi-device pjit traces run
-    on placeholder devices), an unmeasurable dtype, or a shape over
-    ``max_measure_flops`` — it falls back to ``AnalyticPolicy`` (which
-    ranks tiles by the roofline model) so dispatch always proceeds, tiled.
+    wide) — right there at trace time (``core/measure.py`` times compiled
+    executables on the device from a thread outside the trace), stores
+    the result, and persists the cache.  When measurement is disabled or
+    impossible — ``measure=False``, ``distributed=True`` (multi-device
+    pjit traces run on placeholder devices), an unmeasurable dtype, a
+    shape over ``max_measure_flops``, or no arm that ran — it falls back
+    to ``AnalyticPolicy`` (which ranks tiles by the roofline model) so
+    dispatch always proceeds, tiled; each such key is counted in
+    ``n_fallbacks`` and announced once with a warning.
 
-    Cache keys include the jax platform and hardware name, so one file can
-    hold measurements from several backends without cross-talk.
+    Cache keys include the jax platform and the measuring device's
+    descriptor (``hardware.device_spec``), so one file can hold
+    measurements from several backends without cross-talk.
     """
 
     def __init__(
@@ -463,7 +468,7 @@ class AutotunePolicy(PolicyBase):
     ):
         from .measure import MeasurementCache
 
-        super().__init__(hardware=hardware or host_spec(), **kw)
+        super().__init__(hardware=hardware or device_spec(), **kw)
         if cache is None:
             # recover=True: a corrupt/truncated cache file is moved aside
             # and rebuilt empty — autotune re-measures instead of crashing
@@ -500,20 +505,22 @@ class AutotunePolicy(PolicyBase):
         # shapes where measurement produced nothing — don't retry them every
         # select (in-memory only: a later session/platform may succeed)
         self._unmeasurable: set = set()
+        self._warned: set = set()  # keys whose fallback was announced
         # platform-keyed decision memo (same pattern as MTNNSelector /
         # AnalyticPolicy): repeat selects skip the re-filter + argmin scan
         self._decisions: Dict[Tuple[str, OpKey], Decision] = {}
 
-    def _can_measure(self, dtype: Optional[str], flops: float) -> bool:
-        from .measure import measurement_supported
-
-        return (
-            self.measure
-            and not self.distributed
-            and dtype is not None
-            and flops <= self.max_measure_flops
-            and measurement_supported()
-        )
+    def _why_not_measured(self, dtype: Optional[str], flops: float) -> Optional[str]:
+        """Why a cold key cannot be measured here (None: it can be)."""
+        if not self.measure:
+            return "measurement is disabled"
+        if self.distributed:
+            return "multi-device programs are not measured"
+        if dtype is None:
+            return "its element size has no measurable dtype"
+        if flops > self.max_measure_flops:
+            return f"{flops:.3g} FLOPs exceed max_measure_flops"
+        return None
 
     def select(self, key: OpKey) -> Decision:
         from repro.kernels.tiling import parse_config_key
@@ -541,11 +548,16 @@ class AutotunePolicy(PolicyBase):
             key.k,
         )
         times = self.cache.get(cache_key)
+        why = None
         if times is not None:
             self.n_cache_hits += 1
-        elif cache_key not in self._unmeasurable and self._can_measure(
-            dtype, 2.0 * key.g * key.m * key.n * key.k
-        ):
+        elif cache_key in self._unmeasurable:
+            why = "no arm could be measured"
+        else:
+            why = self._why_not_measured(
+                dtype, 2.0 * key.g * key.m * key.n * key.k
+            )
+        if times is None and why is None:
             attempts: Dict[str, Dict[str, int]] = {}
             times = measure_candidates(
                 key.m, key.n, key.k,
@@ -569,6 +581,7 @@ class AutotunePolicy(PolicyBase):
                     self.cache.save()
             else:
                 self._unmeasurable.add(cache_key)
+                why = "no arm could be measured"
         decision = None
         if times:
             # re-filter at use time: cached entries may predate a registry /
@@ -597,6 +610,15 @@ class AutotunePolicy(PolicyBase):
             # own platform-keyed memo, and a later measurement may succeed
             self.n_fallbacks += 1
             decision = self.fallback.select(key)
+            if cache_key not in self._warned:
+                self._warned.add(cache_key)
+                warnings.warn(
+                    f"AutotunePolicy: {key} has no usable measurement "
+                    f"({why or 'no measured arm is admissible'}); the "
+                    f"analytic model picks {decision.label()}",
+                    UserWarning,
+                    stacklevel=2,
+                )
         self.stats.record(decision.name, decision.config, op=key.op)
         return decision
 

@@ -47,7 +47,7 @@ from .candidates import (
 )
 from .features import make_features
 from .gbdt import GBDTClassifier
-from .hardware import SIMULATED_CHIPS, TPU_V5E, HardwareSpec
+from .hardware import SIMULATED_CHIPS, HardwareSpec, target_spec
 from .opkey import OpKey, check_op, coerce_key, parse_shape_key, shape_key
 from .train_model import KWayModel
 
@@ -169,7 +169,7 @@ class MTNNSelector:
         tile_tables: Optional[Dict[str, Dict[str, Dict]]] = None,
     ):
         self.model = model
-        self.hardware = hardware or TPU_V5E
+        self.hardware = hardware or target_spec()
         self.mode = mode
         # per-op binary pairs; `binary_pair` keeps naming the NT pair (the
         # paper's setting and the pre-op-space API)
@@ -444,7 +444,11 @@ class MTNNSelector:
             return _fresh_fallback_selector(
                 hardware=hardware, distributed=distributed
             )
-        hw = hardware or SIMULATED_CHIPS.get(payload.get("hardware", ""), TPU_V5E)
+        hw = (
+            hardware
+            or SIMULATED_CHIPS.get(payload.get("hardware", ""))
+            or target_spec()
+        )
         # tolerate hand-authored v3 payloads omitting the field: the
         # standard per-op pairs are the documented default
         pairs = {

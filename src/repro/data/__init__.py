@@ -1,3 +1,3 @@
-from .pipeline import DataConfig, SyntheticLM, make_train_batch
+from .pipeline import DataConfig, SyntheticLM, make_fcn_batch, make_train_batch
 
-__all__ = ["DataConfig", "SyntheticLM", "make_train_batch"]
+__all__ = ["DataConfig", "SyntheticLM", "make_train_batch", "make_fcn_batch"]

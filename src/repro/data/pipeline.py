@@ -17,7 +17,7 @@ from typing import Dict
 
 import numpy as np
 
-__all__ = ["DataConfig", "SyntheticLM", "make_train_batch"]
+__all__ = ["DataConfig", "SyntheticLM", "make_train_batch", "make_fcn_batch"]
 
 
 @dataclass(frozen=True)
@@ -85,3 +85,13 @@ def make_train_batch(
     ).batch(step)
     patches = rng.randn(B, arch_cfg.prefix_len, arch_cfg.d_model).astype(np.float32) * 0.02
     return {"patches": patches, "tokens": lm["tokens"], "labels": lm["labels"]}
+
+
+def make_fcn_batch(
+    rng: np.random.RandomState, fcn_cfg, batch: int, w_true: np.ndarray
+) -> Dict[str, np.ndarray]:
+    """A synthetic batch for the paper's FCN with a learnable rule: ``x``
+    is standard normal, ``labels = argmax(x @ w_true) % output_dim``."""
+    x = rng.randn(batch, fcn_cfg.input_dim).astype(np.float32)
+    labels = (x @ w_true).argmax(-1) % fcn_cfg.output_dim
+    return {"x": x, "labels": labels.astype(np.int32)}

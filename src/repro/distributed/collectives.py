@@ -22,16 +22,6 @@ from jax.sharding import PartitionSpec as P
 
 __all__ = ["quantize_int8", "dequantize_int8", "compressed_psum", "compressed_mean"]
 
-# jax >= 0.6 promotes shard_map to jax.shard_map (kw: check_vma); older
-# versions ship it in jax.experimental (kw: check_rep).
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_NO_CHECK = {"check_vma": False}
-else:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_NO_CHECK = {"check_rep": False}
-
 _CHUNK = 2048
 
 
@@ -80,8 +70,8 @@ def compressed_psum(grads, mesh: Mesh, axes: Tuple[str, ...]):
         return jax.tree.map(lambda g: _psum_quantized(g, axes), g_tree)
 
     specs = jax.tree.map(lambda _: P(), grads)
-    fn = _shard_map(
-        body, mesh=mesh, in_specs=(specs,), out_specs=specs, **_SHARD_MAP_NO_CHECK
+    fn = jax.shard_map(
+        body, mesh=mesh, in_specs=(specs,), out_specs=specs, check_vma=False
     )
     return fn(grads)
 

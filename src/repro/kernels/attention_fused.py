@@ -162,7 +162,6 @@ def attn_grid_spec(
         name="attention_fused",
         grid=(g, cdiv(mp, bq), n_kv),
         in_specs=(
-            BlockMap((1, 1), lambda gi, i, kk: (gi, 0), (g, 1)),  # lengths
             BlockMap((1, bq, dhp), lambda gi, i, kk: (gi, i, 0), (g, mp, dhp)),
             kv_map,  # k
             kv_map,  # v
@@ -171,7 +170,14 @@ def attn_grid_spec(
             (1, bq, dhp), lambda gi, i, kk: (gi, i, 0), (g, mp, dhp)
         ),
         sequential=(2,),
+        scalar_prefetch=1,  # lengths (g,) int32, whole, in SMEM
     )
+
+
+def _grid_only(index_map, n_grid: int):
+    """Pallas appends the scalar-prefetch refs to every index map's
+    arguments; the spec's maps read the grid indices alone."""
+    return lambda *args: index_map(*args[:n_grid])
 
 
 def _kernel(
@@ -204,6 +210,7 @@ def _kernel(
     # so every position below derives from jj, not ki.
     qi, ki = pl.program_id(1), pl.program_id(2)
     jj = ki if kv_lo is None else kv_lo(qi) + ki
+    length = len_ref[pl.program_id(0)]  # this slice's valid key count (SMEM)
 
     # Block-level skip: a kv block with no visible (row, col) pair
     # contributes exactly nothing to the online-softmax state (its exp'd
@@ -213,7 +220,7 @@ def _kernel(
     # arithmetic on the program ids; the whole update sits under one cond.
     k_blo = mask.k_start + jj * bk  # lowest k_pos in block
     k_bhi = k_blo + bk - 1
-    live = jj * bk < len_ref[0, 0]  # any valid column at all
+    live = jj * bk < length  # any valid column at all
     if mask.causal or mask.window:
         # q_pos range of this block: rows r in [i*bq, i*bq + bq) map to
         # q_start + r % q_seg — a whole segment unless the block sits
@@ -251,7 +258,7 @@ def _kernel(
         cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         q_row = qi * bq + rows
         k_col = jj * bk + cols
-        valid = k_col < len_ref[0, 0]
+        valid = k_col < length
         q_pos = mask.q_start + q_row % q_seg
         k_pos = mask.k_start + k_col
         vis = valid
@@ -267,7 +274,7 @@ def _kernel(
         # (not 0 — exp(NEG_INF - NEG_INF)), so junk V rows must not be
         # summable.
         vcols = jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
-        vb = jnp.where(jj * bk + vcols < len_ref[0, 0], vb, 0)
+        vb = jnp.where(jj * bk + vcols < length, vb, 0)
 
         # online-softmax update: rescale the running state by alpha, fold
         # in this block's exp'd scores.  All state f32.
@@ -322,32 +329,41 @@ def attention_fused(
         f"attention operand mismatch: {q.shape} vs {k.shape} vs {v.shape}"
     )
     if lengths is None:
-        lengths = jnp.full((g, 1), n, jnp.int32)
+        lengths = jnp.full((g,), n, jnp.int32)
     else:
-        lengths = jnp.asarray(lengths, jnp.int32).reshape(g, 1)
+        lengths = jnp.asarray(lengths, jnp.int32).reshape(g)
     spec = attn_grid_spec(g, m, n, dh, block=block, mask=mask)
     _, mp, dhp = spec.out_spec.extent
-    np_ = spec.in_specs[2].extent[1]
-    bq, bk = spec.out_spec.block[1], spec.in_specs[2].block[1]
+    np_ = spec.in_specs[1].extent[1]
+    bq, bk = spec.out_spec.block[1], spec.in_specs[1].block[1]
     _, kv_lo = _kv_band(mp, np_, bq, bk, mask)
     qp = _pad3(q, mp, dhp)
     kp = _pad3(k, np_, dhp)
     vp = _pad3(v, np_, dhp)
     interp = should_interpret() if interpret is None else interpret
+    n_grid = len(spec.grid)
 
     out = pl.pallas_call(
         functools.partial(
             _kernel, n_kv=spec.grid[2], bq=bq, bk=bk, mask=mask, kv_lo=kv_lo
         ),
-        grid=spec.grid,
-        in_specs=[pl.BlockSpec(s.block, s.index_map) for s in spec.in_specs],
-        out_specs=pl.BlockSpec(spec.out_spec.block, spec.out_spec.index_map),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=spec.scalar_prefetch,
+            grid=spec.grid,
+            in_specs=[
+                pl.BlockSpec(s.block, _grid_only(s.index_map, n_grid))
+                for s in spec.in_specs
+            ],
+            out_specs=pl.BlockSpec(
+                spec.out_spec.block, _grid_only(spec.out_spec.index_map, n_grid)
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((bq, dhp), jnp.float32),  # output accumulator
+                pltpu.VMEM((bq, MXU_EDGE), jnp.float32),  # running max
+                pltpu.VMEM((bq, MXU_EDGE), jnp.float32),  # running denominator
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct(spec.out_spec.extent, q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq, dhp), jnp.float32),  # output accumulator
-            pltpu.VMEM((bq, MXU_EDGE), jnp.float32),  # running max
-            pltpu.VMEM((bq, MXU_EDGE), jnp.float32),  # running denominator
-        ],
         compiler_params=CompilerParams(
             dimension_semantics=spec.dimension_semantics
         ),

@@ -26,8 +26,7 @@ __all__ = [
     "CompilerParams",
 ]
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; support both.
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+CompilerParams = pltpu.CompilerParams
 
 MXU_EDGE = 128
 # Default VMEM tile for the matmul family: (bm, bn, bk).  At bf16 this is

@@ -63,6 +63,13 @@ class KernelGridSpec:
     are "parallel": two grid points that differ on a parallel axis may
     execute concurrently, so they must never write the same output
     block.
+
+    ``scalar_prefetch`` counts the leading kernel operands that are not
+    blocked at all: small int32 arrays handed whole to SMEM ahead of the
+    grid (``pltpu.PrefetchScalarGridSpec``) — the fused attention's
+    per-slice ``lengths``.  They have no ``BlockMap``; ``in_specs`` lists
+    the blocked operands that follow them, and every index map takes the
+    grid indices only (the kernel drops the prefetch refs Pallas appends).
     """
 
     name: str
@@ -70,6 +77,7 @@ class KernelGridSpec:
     in_specs: Tuple[BlockMap, ...]
     out_spec: BlockMap
     sequential: Tuple[int, ...] = ()
+    scalar_prefetch: int = 0
 
     @property
     def dimension_semantics(self) -> Tuple[str, ...]:

@@ -237,9 +237,9 @@ def transpose_config_space(
     from repro.core.simulate import transpose_tile_time
 
     if hardware is None:
-        from repro.core.hardware import TPU_V5E
+        from repro.core.hardware import target_spec
 
-        hardware = TPU_V5E
+        hardware = target_spec()
     configs = enumerate_transpose_configs(rows, cols, dsize, vmem_budget)
     ranked = sorted(
         configs,
@@ -270,9 +270,9 @@ def shortlist_tile_configs(
     from repro.core.simulate import tile_time
 
     if hardware is None:
-        from repro.core.hardware import TPU_V5E
+        from repro.core.hardware import target_spec
 
-        hardware = TPU_V5E
+        hardware = target_spec()
     configs = enumerate_tile_configs(m, n, k, dsize, vmem_budget)
     ranked = sorted(configs, key=lambda c: tile_time(hardware, m, n, k, dsize, c))
     if 0 < max_configs < len(ranked):
@@ -364,9 +364,9 @@ def attn_config_space(
     from repro.core.simulate import attn_tile_time
 
     if hardware is None:
-        from repro.core.hardware import TPU_V5E
+        from repro.core.hardware import target_spec
 
-        hardware = TPU_V5E
+        hardware = target_spec()
     configs = enumerate_attn_configs(m, n, dh, dsize, vmem_budget)
     ranked = sorted(
         configs,

@@ -1,4 +1,5 @@
-"""Shared launcher CLI setup: mesh-spec parsing + policy wiring.
+"""Shared launcher CLI setup: mesh-spec parsing + policy wiring, and the
+persistent compilation cache the entry points turn on.
 
 ``train.py`` and ``serve.py`` used to duplicate this block — including a
 bug where ``--mesh 4`` or ``--mesh axb`` crashed with a raw ``ValueError``
@@ -9,17 +10,44 @@ actionable error; ``resolve_mesh_and_policy`` turns that into
 
 from __future__ import annotations
 
+import os
+
 import jax
 
 from repro.core.engine import policy_from_spec
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 
 __all__ = [
+    "CHECKOUT_CACHE_DIR",
+    "enable_compile_cache",
     "MESH_SPEC_HELP",
     "parse_mesh",
     "add_mesh_argument",
     "resolve_mesh_and_policy",
 ]
+
+# <checkout>/.jax_cache: a fixed path (JAX keys cache entries by it), listed
+# in .gitignore
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))),
+    ".jax_cache",
+)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no
+    directory is set here; otherwise the cache lives in
+    ``CHECKOUT_CACHE_DIR``.  Entry points call this before their first
+    compile (never at import, so the test suite runs with the cache off)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    return CHECKOUT_CACHE_DIR
+
 
 MESH_SPEC_HELP = (
     "mesh spec: DATAxMODEL with two positive integers (e.g. 1x1, 2x4) "

@@ -5,21 +5,13 @@ XLA_FLAGS *before* any jax init)."""
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-# jax >= 0.5 has explicit axis types; older versions default to Auto.
-try:
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - version-dependent
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 __all__ = ["make_production_mesh", "make_local_mesh"]
 
 
 def _mesh(shape, axes) -> Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -33,7 +33,11 @@ from repro.core.engine import (
 )
 from repro.core.faults import add_chaos_argument, chaos_scope
 from repro.distributed import named, param_specs
-from repro.launch.common import add_mesh_argument, resolve_mesh_and_policy
+from repro.launch.common import (
+    add_mesh_argument,
+    enable_compile_cache,
+    resolve_mesh_and_policy,
+)
 from repro.launch.steps import make_prefill_step, make_serve_step
 from repro.models import lm
 
@@ -61,6 +65,14 @@ def _build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--deadline-s", type=float, default=None,
                     help="per-request deadline in seconds; overdue requests "
                          "are evicted as DEADLINE_EXCEEDED")
+    ap.add_argument("--classes", default=",".join(DEFAULT_CLASSES),
+                    help="comma-separated request classes; requests are "
+                         "assigned round-robin, each class compiles its own "
+                         "steps")
+    ap.add_argument("--len-step", type=int, default=0,
+                    help="prefill bucket grid step (default: 16, raised to "
+                         "the attention window); every bucket is compiled "
+                         "at warmup")
     ap.add_argument("--class-policy", action="append", default=[],
                     metavar="CLS=SPEC",
                     help=f"per-class policy override, e.g. bulk=analytic; "
@@ -81,7 +93,10 @@ def _build_parser() -> argparse.ArgumentParser:
 def _class_policies(args, parser, distributed: bool):
     """One *fresh* policy instance per request class (stats must not mix
     across classes), honouring ``--class-policy CLS=SPEC`` overrides."""
-    specs = {cls: args.policy for cls in DEFAULT_CLASSES}
+    classes = [c.strip() for c in args.classes.split(",") if c.strip()]
+    if not classes:
+        parser.error(f"--classes needs at least one class, got {args.classes!r}")
+    specs = {cls: args.policy for cls in classes}
     for entry in args.class_policy:
         cls, eq, spec = entry.partition("=")
         cls, spec = cls.strip(), spec.strip()
@@ -100,7 +115,7 @@ def _class_policies(args, parser, distributed: bool):
 
 
 def _engine_main(args, parser):
-    from repro.serving import QueueFullError, ServeEngine
+    from repro.serving import QueueFullError, ServeEngine, default_buckets
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh, _ = resolve_mesh_and_policy(args, parser)
@@ -111,9 +126,17 @@ def _engine_main(args, parser):
     with mesh:
         params = jax.device_put(params, named(mesh, param_specs(params, mesh)))
 
+    bucket_spec = None
+    if args.len_step:
+        windows = [b.window for _, blocks in cfg.segments for b in blocks
+                   if b.window is not None]
+        bucket_spec = default_buckets(
+            args.slots, max_seq, window=max(windows, default=0),
+            len_step=args.len_step,
+        )
     engine = ServeEngine(
         cfg, params, n_slots=args.slots, max_seq=max_seq,
-        policies=policies, mesh=mesh,
+        policies=policies, mesh=mesh, bucket_spec=bucket_spec,
         budget_tokens=args.budget_tokens or None,
         max_queue=args.max_queue or None,
     )
@@ -224,13 +247,22 @@ def _legacy_main(args, parser):
 
 
 def main(argv=None):
+    """Run the driver; a run in which any engine step crashed exits
+    non-zero (``SystemExit``) after its reports are printed."""
     parser = _build_parser()
     args = parser.parse_args(argv)
     with chaos_scope(args.chaos):
         if args.legacy:
             return _legacy_main(args, parser)
-        return _engine_main(args, parser)
+        engine = _engine_main(args, parser)
+    if engine.crashed_steps:
+        raise SystemExit(
+            f"[serve] {engine.crashed_steps} engine step(s) crashed; their "
+            "requests were evicted"
+        )
+    return engine
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -25,10 +25,17 @@ from repro.distributed import (
     param_specs,
 )
 from repro.models import lm
-from repro.optim import clip_by_global_norm, make_optimizer, warmup_cosine
+from repro.models.fcn import fcn_loss
+from repro.optim import (
+    adamw_update,
+    clip_by_global_norm,
+    make_optimizer,
+    warmup_cosine,
+)
 
 __all__ = [
     "make_train_step",
+    "make_fcn_train_step",
     "make_prefill_step",
     "make_serve_step",
     "train_state_shapes",
@@ -179,6 +186,25 @@ def make_train_step(
         return new_state, metrics
 
     return train_step
+
+
+def make_fcn_train_step(
+    policy: Optional[SelectionPolicy], sched: Callable, max_grad_norm: float = 1.0
+) -> Callable:
+    """One AdamW step of the paper's FCN (``models/fcn.py``):
+    ``step_fn(params, opt, step, batch) -> (params, opt, loss, gnorm)``.
+    Every layer GEMM, forward and backward, dispatches under ``policy``."""
+
+    def step_fn(params, opt, step, batch):
+        with _policy_scope(policy):
+            (loss, _), grads = jax.value_and_grad(
+                lambda p: fcn_loss(p, batch), has_aux=True
+            )(params)
+        grads, gnorm = clip_by_global_norm(grads, max_grad_norm)
+        params, opt = adamw_update(grads, opt, params, sched(step))
+        return params, opt, loss, gnorm
+
+    return step_fn
 
 
 def make_prefill_step(

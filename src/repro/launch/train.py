@@ -20,9 +20,11 @@ Example (CPU, reduced config):
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import statistics
 import time
+from typing import Any, List
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +36,11 @@ from repro.core.engine import add_policy_argument, dispatch_report, health_repor
 from repro.core.faults import add_chaos_argument, chaos_scope
 from repro.data import make_train_batch
 from repro.distributed import batch_specs, named
-from repro.launch.common import add_mesh_argument, resolve_mesh_and_policy
+from repro.launch.common import (
+    add_mesh_argument,
+    enable_compile_cache,
+    resolve_mesh_and_policy,
+)
 from repro.launch.steps import (
     TrainStepConfig,
     make_train_step,
@@ -43,6 +49,18 @@ from repro.launch.steps import (
 )
 from repro.models import lm
 from repro.optim import make_optimizer
+
+
+@dataclasses.dataclass
+class TrainResult:
+    """What a ``main`` run leaves behind: the final state, the loss of
+    every step run, the policy that dispatched it, and the compiled step."""
+
+    state: Any
+    losses: List[float]
+    policy: Any
+    compiled: Any
+    compile_s: float
 
 
 def build_state(cfg, mesh, state_specs, seed: int = 0):
@@ -119,18 +137,27 @@ def _run(args, ap):
         state = build_state(cfg, mesh, state_specs, seed=args.seed)
         print(f"[train] fresh init ({cfg.name}, {cfg.param_count()/1e6:.1f}M params)")
 
-    times = []
+    # compile ahead of the loop: step times then exclude compilation
+    t0 = time.perf_counter()
+    with mesh:
+        compiled = jitted.lower(
+            state, jax.device_put(dummy, named(mesh, b_specs))
+        ).compile()
+    compile_s = time.perf_counter() - t0
+    print(f"[train] step compiled in {compile_s:.1f}s")
+
+    times, losses = [], []
     for step in range(start_step, args.steps):
         if args.fail_at == step:
             raise RuntimeError(f"[train] injected failure at step {step}")
         batch = make_train_batch(cfg, args.seq, args.batch, step, seed=args.seed)
         batch = jax.device_put(batch, named(mesh, b_specs))
         t0 = time.perf_counter()
-        with mesh:
-            state, metrics = jitted(state, batch)
+        state, metrics = compiled(state, batch)
         jax.block_until_ready(metrics["loss"])
         dt = time.perf_counter() - t0
         times.append(dt)
+        losses.append(float(metrics["loss"]))
         if len(times) > 5:
             med = statistics.median(times[-50:])
             if dt > args.straggler_factor * med:
@@ -146,12 +173,14 @@ def _run(args, ap):
     if ckpt is not None:
         ckpt.wait()
         ckpt.save(args.steps, state)
-    print(f"[train] done: {args.steps - start_step} steps, "
-          f"median {statistics.median(times)*1e3:.0f} ms/step")
+    if times:
+        print(f"[train] done: {args.steps - start_step} steps, "
+              f"median {statistics.median(times)*1e3:.0f} ms/step")
     print(dispatch_report(policy))
     print(health_report())
-    return state
+    return TrainResult(state, losses, policy, compiled, compile_s)
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

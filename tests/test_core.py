@@ -3,6 +3,7 @@ selector dispatch, paper-metric computation."""
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro import core
 from repro.core.gbdt import DecisionTreeClassifier, GBDTClassifier, GBDTRegressor
@@ -198,3 +199,26 @@ class TestSelector:
         for mnk in [(128, 128, 128), (4096, 4096, 4096), (65536, 512, 65536)]:
             name = sel.select(core.OpKey("NT", *mnk))
             assert core.CANDIDATES[name].distributed_safe
+
+
+class TestDeviceSpec:
+    @staticmethod
+    def _dev(platform, kind):
+        import types
+
+        return types.SimpleNamespace(platform=platform, device_kind=kind)
+
+    def test_v5e_kind_maps_to_its_spec(self):
+        assert core.device_spec(self._dev("tpu", "TPU v5 lite")) is core.TPU_V5E
+
+    @pytest.mark.parametrize("kind", ["TPU v9 mega", "TPU v5 lite "])
+    def test_unknown_tpu_kind_raises(self, kind):
+        with pytest.raises(ValueError, match="no HardwareSpec"):
+            core.device_spec(self._dev("tpu", kind))
+
+    def test_cpu_maps_to_host_spec(self):
+        assert core.device_spec(self._dev("cpu", "cpu")) == core.host_spec()
+
+    def test_target_spec_on_cpu_models_the_v5e(self):
+        # kernels run in interpret mode here as a rehearsal of the v5e path
+        assert core.target_spec() is core.TPU_V5E

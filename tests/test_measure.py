@@ -18,8 +18,8 @@ from repro.core.measure import (
     MeasurementCache,
     best_times,
     default_cache_path,
+    bench_fn,
     measure_candidates,
-    measurement_supported,
     top_configs_by_candidate,
 )
 
@@ -320,8 +320,96 @@ class TestMeasureHarness:
         assert times
         assert all(core.get_candidate(n).distributed_safe for n in times)
 
-    def test_supported_eagerly(self):
-        assert measurement_supported()
+
+
+# -- measurement inside a jit trace -------------------------------------------
+
+
+_SLEEP_S = 0.05
+
+
+def _slow_nt(a, b):
+    """XLA_NT plus a host callback that sleeps while the program *runs*:
+    only executed code costs the sleep, tracing it costs nothing."""
+    import time
+
+    def wait(x):
+        time.sleep(_SLEEP_S)
+        return x
+
+    out = core.get_candidate("XLA_NT").fn(a, b)
+    return jax.pure_callback(wait, jax.ShapeDtypeStruct(out.shape, out.dtype), out)
+
+
+@pytest.fixture
+def slow_candidate():
+    core.register_candidate("TEST_SLOW_NT", sim_algo="NT_DIRECT")(_slow_nt)
+    try:
+        yield "TEST_SLOW_NT"
+    finally:
+        core.unregister_candidate("TEST_SLOW_NT")
+
+
+class TestMeasureInsideTrace:
+    def test_times_executed_code_not_tracing(self, slow_candidate):
+        """Selection runs while jit traces; the recorded time must be the
+        executable's run time (>= the sleep), not the trace time."""
+        got = {}
+
+        def traced(a):
+            got.update(
+                measure_candidates(
+                    8, 8, 8, candidates=(slow_candidate,), reps=1, warmup=1
+                )
+            )
+            return a
+
+        jax.jit(traced)(jnp.ones(3))
+        assert got[slow_candidate]["default"] >= _SLEEP_S
+
+    def test_timed_callable_gets_concrete_arrays(self, monkeypatch):
+        seen = []
+        real = bench_fn
+
+        def spy(fn, *operands, **kw):
+            seen.extend(operands)
+            return real(fn, *operands, **kw)
+
+        monkeypatch.setattr("repro.core.measure.bench_fn", spy)
+        jax.jit(
+            lambda a: (
+                measure_candidates(8, 8, 8, candidates=("XLA_NT",), reps=1),
+                a,
+            )[1]
+        )(jnp.ones(3))
+        assert seen
+        assert all(
+            isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer)
+            for x in seen
+        )
+
+    def test_bench_fn_refuses_tracers(self):
+        def traced(a):
+            with pytest.raises(TypeError, match="concrete"):
+                bench_fn(lambda x: x + 1, a)
+            return a
+
+        jax.jit(traced)(jnp.ones(3))
+
+    def test_autotune_inside_jit_records_run_time(self, slow_candidate):
+        pol = core.AutotunePolicy(candidates=(slow_candidate,), reps=1)
+        a, b = jnp.ones((8, 8)), jnp.ones((8, 8))
+        with core.use_policy(pol):
+            jax.jit(lambda a, b: core.dispatch("NT", a, b))(a, b)
+        assert pol.n_measured == 1 and pol.n_fallbacks == 0
+        (times,) = [t for _, t in pol.cache.records()]
+        assert times[slow_candidate]["default"] >= _SLEEP_S
+
+    def test_fallback_is_announced(self):
+        pol = core.AutotunePolicy(measure=False)
+        with pytest.warns(UserWarning, match="measurement is disabled"):
+            pol.select(_nt(64, 64, 64))
+        assert pol.n_fallbacks == 1
 
 
 # -- AutotunePolicy -----------------------------------------------------------

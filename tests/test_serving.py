@@ -399,3 +399,33 @@ class TestMeshParsing:
         args = argparse.Namespace(mesh="bogus", policy="model")
         with pytest.raises(ValueError, match="mesh spec"):
             resolve_mesh_and_policy(args)
+
+
+# -- the serve driver -----------------------------------------------------------
+
+_DRIVER_ARGS = [
+    "--arch", "smollm-135m", "--smoke", "--requests", "2",
+    "--prompt-len", "8", "--gen", "8", "--slots", "2", "--mesh", "1x1",
+    "--policy", "fixed:XLA_NT", "--classes", "interactive", "--len-step", "8",
+]
+
+
+class TestServeDriver:
+    def test_classes_and_len_step_reach_the_engine(self):
+        from repro.launch import serve
+
+        engine = serve.main(_DRIVER_ARGS)
+        assert set(engine.policies) == {"interactive"}
+        assert engine.buckets.len_step == 8
+        assert engine.health()["finished"] == 2
+
+    def test_crashed_step_exits_nonzero(self, monkeypatch):
+        from repro.launch import serve
+
+        def boom(self, cls, reqs):
+            raise RuntimeError("decode step lost")
+
+        monkeypatch.setattr(ServeEngine, "_decode_class", boom)
+        with pytest.warns(UserWarning, match="crashed"):
+            with pytest.raises(SystemExit, match="crashed"):
+                serve.main(_DRIVER_ARGS)

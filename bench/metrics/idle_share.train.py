@@ -1,0 +1,7 @@
+"""Share of the traced training window in which no op ran on the chip."""
+
+
+def read(run):
+    if run.counters.get("kind") != "train" or run.trace_data is None:
+        return None
+    return 100.0 * run.trace_data.idle_share()

@@ -9,10 +9,10 @@ the engine's *virtual* clock (``engine.clock``), so two runs with the
 same seed submit byte-identical traffic.  Wall-clock only enters as the
 thing being measured (tokens/sec, per-token latency) — never as an input.
 
-Reports, per class and total: tokens/sec, p50/p99 per-token decode
-latency, and the structured dispatch rows (op -> candidate -> count) so
-CI can assert that batched attention contractions (BNT/BNN) route
-through each class's own policy.  ``cold_misses_after_warmup`` must be
+Reports, per class and total: tokens/sec, p50/p99 of the gaps between a
+request's tokens as they reached the host, and the structured dispatch
+rows (op -> candidate -> count) so CI can assert that batched attention
+contractions (BNT/BNN) route through each class's own policy.  ``cold_misses_after_warmup`` must be
 zero: the bucketed serve loop may only hit OpKeys the warmup pass
 already measured.
 
@@ -96,8 +96,9 @@ def run_load(args) -> dict:
     per_class = {}
     for cls in classes:
         cls_reqs = [r for r in reqs if r.cls == cls]
-        # token_lat[0] is the prefill (first token); the rest are decode steps
-        lats = [t for r in cls_reqs for t in r.token_lat[1:]]
+        # gaps between a request's tokens as they reached the host
+        lats = [b - a for r in cls_reqs
+                for a, b in zip(r.token_times, r.token_times[1:])]
         per_class[cls] = {
             "policy": repr(policies[cls]),
             "requests": len(cls_reqs),

@@ -45,6 +45,8 @@ clean ``TypeError``/``AttributeError``s pointing at the op-space API.
 from __future__ import annotations
 
 import functools
+import os
+import re
 import warnings
 from typing import Optional
 
@@ -53,7 +55,7 @@ import numpy as np
 
 from . import faults
 from .candidates import DEFAULT_BY_OP, fallback_chain, get_candidate
-from .opkey import BATCHED_OPS, OPS, OpKey, check_op
+from .opkey import BATCHED_OPS, GROUPED_OPS, OPS, OpKey, check_op
 from .policy import (
     AnalyticPolicy,
     AutotunePolicy,
@@ -159,6 +161,31 @@ def _decision_chain(op: str, decision: Decision) -> list:
     return chain
 
 
+# The scopes live in the ops' metadata, which JAX's persistent compile
+# cache leaves out of its key unless asked: an executable cached from a
+# program without scopes, or with other scope names, would otherwise be
+# served for this one.  Source paths in the metadata are made relative to
+# the checkout, so that the key does not depend on where the checkout lies.
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                  "^" + re.escape(_CHECKOUT + os.sep))
+
+
+def scope_name(key: OpKey, decision: Decision) -> str:
+    """The named scope an executed arm's ops carry in the compiled HLO's
+    ``op_name`` metadata (and so in the device trace):
+    ``dispatch.<op>.<m>x<n>x<k>[.g<g>].d<dsize>.<arm label>``, e.g.
+    ``dispatch.NT.4096x26752x4096.d4.PALLAS_TNN_FUSED@128x128x128`` or
+    ``dispatch.BNT.3072x2048x64.g24.d4.XLA_BNT``.  ``g`` is written for
+    the batched ops and ATTN only.  A scope changes metadata alone: the
+    compiled program is the same op for op."""
+    g = f".g{key.g}" if key.op in GROUPED_OPS else ""
+    return (f"dispatch.{key.op}.{key.m}x{key.n}x{key.k}{g}.d{key.dsize}."
+            f"{decision.label()}")
+
+
 def run_decision(key: OpKey, decision: Decision, a, b):
     """Execute a policy decision fault-tolerantly.
 
@@ -177,7 +204,8 @@ def run_decision(key: OpKey, decision: Decision, a, b):
             continue
         try:
             faults.check_candidate_fault(dec.name, key.op)
-            out = get_candidate(dec.name).run(a, b, dec.config)
+            with jax.named_scope(scope_name(key, dec)):
+                out = get_candidate(dec.name).run(a, b, dec.config)
         except (KeyboardInterrupt, SystemExit):
             raise
         except Exception as e:
@@ -373,6 +401,16 @@ def _unfused_attn_plan(mask, q, k, v, lengths):
     return out.astype(q.dtype)
 
 
+def _attn_key(q, k) -> OpKey:
+    import jax.numpy as jnp
+
+    g, m, dh = q.shape
+    return OpKey(
+        "ATTN", int(m), int(k.shape[1]), int(dh),
+        int(jnp.dtype(q.dtype).itemsize), int(g),
+    )
+
+
 def _run_attn(mask, q, k, v, lengths):
     """Select and execute the attention plan (the custom_vjp core).
 
@@ -381,14 +419,7 @@ def _run_attn(mask, q, k, v, lengths):
     ``Candidate.run``: ``FUSED_ATTN`` runs the flash kernel with the
     mask folded inside; every other arm (``UNFUSED_ATTN`` included)
     runs the unfused sub-dispatch plan."""
-    import jax.numpy as jnp
-
-    g, m, dh = q.shape
-    n = k.shape[1]
-    key = OpKey(
-        "ATTN", int(m), int(n), int(dh),
-        int(jnp.dtype(q.dtype).itemsize), int(g),
-    )
+    key = _attn_key(q, k)
     decision = policy_select(current_policy(), key)
     chain = _decision_chain("ATTN", decision)
     last_err: Optional[BaseException] = None
@@ -398,13 +429,14 @@ def _run_attn(mask, q, k, v, lengths):
             continue
         try:
             faults.check_candidate_fault(dec.name, "ATTN")
-            if dec.name == "FUSED_ATTN":
-                from repro.kernels.attention_fused import attention_fused
+            with jax.named_scope(scope_name(key, dec)):
+                if dec.name == "FUSED_ATTN":
+                    from repro.kernels.attention_fused import attention_fused
 
-                block = tuple(dec.config) if dec.config is not None else None
-                out = attention_fused(q, k, v, lengths, mask=mask, block=block)
-            else:
-                out = _unfused_attn_plan(mask, q, k, v, lengths)
+                    block = tuple(dec.config) if dec.config is not None else None
+                    out = attention_fused(q, k, v, lengths, mask=mask, block=block)
+                else:
+                    out = _unfused_attn_plan(mask, q, k, v, lengths)
         except (KeyboardInterrupt, SystemExit):
             raise
         except Exception as e:
@@ -443,9 +475,14 @@ def _dispatch_attn_bwd(mask, res, dout):
     dispatch — dQ/dK/dV land on policy-governed BNT/BNN OpKeys, same
     closure property as ``_dispatch3_bwd``.  ``lengths`` is integral:
     its cotangent is float0."""
+    q, k, v, lengths = res
+    with jax.named_scope(scope_name(_attn_key(q, k), Decision("UNFUSED_ATTN", None))):
+        return _attn_bwd(mask, q, k, v, lengths, dout)
+
+
+def _attn_bwd(mask, q, k, v, lengths, dout):
     import jax.numpy as jnp
 
-    q, k, v, lengths = res
     s_raw = _attn_logits(q, k)
     probs = _attn_probs(mask, s_raw, lengths)  # (g, m, n) f32
     dout32 = dout.astype(jnp.float32)
@@ -511,7 +548,10 @@ def dispatch_attention(
     softmax flash-style (residuals are the operands, never the (m, n)
     probs matrix) and lands every gradient contraction on batched
     gradient OpKeys — wrap the whole ``value_and_grad`` call in one
-    ``use_policy`` scope.
+    ``use_policy`` scope.  Whatever arm ran forward, the backward runs
+    the unfused recomputation, so its ops carry the ATTN key's scope with
+    the ``UNFUSED_ATTN`` arm (``transpose(...)`` precedes it in
+    ``op_name``).
     """
     import jax.numpy as jnp
     from repro.kernels.attention_fused import MaskParams

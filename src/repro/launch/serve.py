@@ -16,7 +16,6 @@ jit_prefill + token-by-token jit_serve over a rectangular batch).
 from __future__ import annotations
 
 import argparse
-import statistics
 import time
 
 import jax
@@ -116,6 +115,7 @@ def _class_policies(args, parser, distributed: bool):
 
 def _engine_main(args, parser):
     from repro.serving import QueueFullError, ServeEngine, default_buckets
+    from repro.serving.engine import HOST_PHASES, WAIT_PHASES
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh, _ = resolve_mesh_and_policy(args, parser)
@@ -163,16 +163,28 @@ def _engine_main(args, parser):
     engine.run()
     t_run = time.perf_counter() - t0
 
-    lats = [
-        t for r in engine.requests.values() for t in r.token_lat[1:]
-    ]  # decode-step latencies (first token = prefill)
-    n_tok = sum(len(r.generated) for r in engine.requests.values())
+    reqs = list(engine.requests.values())
+    # as a client sees them: submit to first token, then token to token
+    ttft = [r.token_times[0] - r.submit_time for r in reqs if r.token_times]
+    itl = [b - a for r in reqs for a, b in zip(r.token_times, r.token_times[1:])]
+    queued = [r.admit_time - r.submit_time for r in reqs if r.admit_time is not None]
+    n_tok = sum(len(r.generated) for r in reqs)
     print(f"[serve] {args.requests} requests, {n_tok} tokens in "
           f"{t_run:.2f}s ({n_tok / max(t_run, 1e-9):.1f} tok/s)")
-    if lats:
-        print(f"[serve] per-token decode latency: "
-              f"p50 {statistics.median(lats) * 1e3:.2f} ms, "
-              f"max {max(lats) * 1e3:.2f} ms")
+    for what, xs in (("time to first token", ttft), ("inter-token latency", itl),
+                     ("queue wait", queued)):
+        if xs:
+            p50, p95 = np.percentile(xs, [50, 95]) * 1e3
+            print(f"[serve] {what}: p50 {p50:.2f} ms, p95 {p95:.2f} ms")
+    st = engine.stats()
+    steps = max(st["steps"], 1)
+    print(f"[serve] engine: {st['steps']} steps, {st['decode_steps']} decode steps, "
+          f"{st['decoded_rows']} rows decoded, {st['prefills']} prefills of "
+          f"{st['prompt_tokens']} prompt tokens padded to {st['prefill_tokens']}")
+    print("[serve] engine ms per step: "
+          + ", ".join(f"{p} {st[p + '_s'] / steps * 1e3:.3f}"
+                      for p in HOST_PHASES + WAIT_PHASES)
+          + f"; host {st['host_s'] / steps * 1e3:.3f} of {st['step_s'] / steps * 1e3:.3f}")
     misses = engine.cold_misses()
     print(f"[serve] post-warmup cold-miss measurements: {misses}")
     health = engine.health()

@@ -46,6 +46,21 @@ to its own ``SelectionPolicy``.  Each class's steps are traced inside
 policies concurrently, and ``class_reports()`` renders one
 ``dispatch_report`` per class.
 
+Observability: each phase of ``step()`` runs inside a profiler span
+(``jax.profiler.TraceAnnotation``, nearly free while no trace is taken):
+``engine.expire``, ``engine.admit`` holding one ``engine.prefill`` (pad,
+launch, KV insert), ``engine.prefill_wait`` (blocked until the first
+token is computed) and ``engine.fetch`` (its copy to the host) per
+admitted request, then per class ``engine.decode`` (build inputs,
+launch), ``engine.decode_wait`` (blocked until the next tokens are
+computed), ``engine.fetch`` and ``engine.retire``.  A wait span ends when
+the program it waits for has ended, so the benchmark's trace reduction
+anchors the host clock on it.  ``stats()`` returns cumulative counters of
+the same phases, with the seconds blocked on the device kept apart from
+host seconds; ``launch/serve.py`` prints them per step.  Each request
+records its admission time and the time each of its tokens reached the
+host (the step's return), on the clock of ``submit_time``.
+
 Decode shapes are bucketed (``buckets.BucketSpec``): the active batch
 rounds up to a small bucket set (padding rows target the cache's null
 slot) and prompt lengths round up to a length grid (right-padded,
@@ -116,11 +131,13 @@ class Request:
     state: RequestState = RequestState.QUEUED
     slot: Optional[int] = None
     generated: List[int] = dataclasses.field(default_factory=list)
-    token_lat: List[float] = dataclasses.field(default_factory=list)
     submit_step: int = -1
     admit_step: int = -1
     finish_step: int = -1
     submit_time: float = 0.0  # monotonic wall clock at submit
+    admit_time: Optional[float] = None  # same clock, at admission
+    # same clock: the return of the step that emitted each token
+    token_times: List[float] = dataclasses.field(default_factory=list)
 
     def overdue(self, now: float) -> bool:
         return (
@@ -140,6 +157,36 @@ class Request:
 
 def _policy_scope(policy: Optional[SelectionPolicy]):
     return use_policy(policy) if policy is not None else contextlib.nullcontext()
+
+
+# host phases of one engine step, and the device waits kept apart from them
+HOST_PHASES = ("expire", "admit", "prefill", "decode", "fetch", "retire")
+WAIT_PHASES = ("prefill_wait", "decode_wait")
+
+
+class _PhaseClock:
+    """Cumulative seconds per phase of ``ServeEngine.step``, each phase
+    also a profiler span ``engine.<phase>``.  Phases nest (``prefill``
+    inside ``admit``); each phase's seconds exclude the phases nested in
+    it, so the phases of one step sum to its wall time.  Used from the
+    engine thread only."""
+
+    def __init__(self):
+        self.seconds: Dict[str, float] = dict.fromkeys(HOST_PHASES + WAIT_PHASES, 0.0)
+        self._nested: List[float] = []
+
+    @contextlib.contextmanager
+    def phase(self, name: str):
+        self._nested.append(0.0)
+        t0 = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(f"engine.{name}"):
+                yield
+        finally:
+            dt = time.perf_counter() - t0
+            self.seconds[name] += dt - self._nested.pop()
+            if self._nested:
+                self._nested[-1] += dt
 
 
 class ServeEngine:
@@ -235,6 +282,12 @@ class ServeEngine:
             self._prefill_steps[cls] = jax.jit(self._make_prefill_step(policy))
         self._measured_at_warmup: Dict[str, int] = {}
         self._warm = False
+        # cumulative step counters (stats()), kept by the engine thread
+        self._phases = _PhaseClock()
+        self._counts: Dict[str, int] = dict.fromkeys(
+            ("steps", "decode_steps", "decoded_rows", "prefills",
+             "prompt_tokens", "prefill_tokens"), 0)
+        self._step_s = 0.0
 
     # -- jitted steps (one trace per class x bucket shape) -----------------
 
@@ -394,18 +447,21 @@ class ServeEngine:
             req.slot = slot
             req.state = RequestState.ACTIVE
             req.admit_step = self.clock
+            req.admit_time = time.monotonic()
             P = req.prompt_len
             Lb = P if self.exact_prefill else self.buckets.bucket_len(P)
-            padded = np.zeros((1, Lb), np.int32)
-            padded[0, :P] = req.tokens
-            t0 = time.perf_counter()
             try:
-                with self._mesh_scope():
+                with self._phases.phase("prefill"), self._mesh_scope():
+                    padded = np.zeros((1, Lb), np.int32)
+                    padded[0, :P] = req.tokens
                     tok, cache = self._prefill_steps[req.cls](
                         self.params, jnp.asarray(padded), jnp.int32(P)
                     )
                     self.kv.insert(cache, slot, P)
-                    tok = int(jax.block_until_ready(tok)[0])
+                with self._phases.phase("prefill_wait"):
+                    jax.block_until_ready(tok)
+                with self._phases.phase("fetch"):
+                    tok = int(np.asarray(tok)[0])
             except (KeyboardInterrupt, SystemExit):
                 raise
             except Exception as e:
@@ -421,7 +477,9 @@ class ServeEngine:
                 )
                 continue
             req.generated.append(tok)
-            req.token_lat.append(time.perf_counter() - t0)
+            self._counts["prefills"] += 1
+            self._counts["prompt_tokens"] += P
+            self._counts["prefill_tokens"] += Lb
             admitted.append(req)
         return admitted
 
@@ -436,31 +494,34 @@ class ServeEngine:
 
     def _decode_class(self, cls: str, reqs: List[Request]) -> None:
         """One bucketed decode step for one class's active requests."""
-        Bb = self.buckets.bucket_batch(len(reqs))
-        slot_ids = np.full(Bb, self.kv.null_slot, np.int32)
-        tok = np.zeros((Bb, 1), np.int32)
-        lengths = np.zeros(Bb, np.int32)
-        for i, req in enumerate(reqs):
-            slot_ids[i] = req.slot
-            tok[i, 0] = req.generated[-1]
-            lengths[i] = self.kv.lengths[req.slot]
-        t0 = time.perf_counter()
-        with self._mesh_scope():
+        with self._phases.phase("decode"), self._mesh_scope():
+            Bb = self.buckets.bucket_batch(len(reqs))
+            slot_ids = np.full(Bb, self.kv.null_slot, np.int32)
+            tok = np.zeros((Bb, 1), np.int32)
+            lengths = np.zeros(Bb, np.int32)
+            for i, req in enumerate(reqs):
+                slot_ids[i] = req.slot
+                tok[i, 0] = req.generated[-1]
+                lengths[i] = self.kv.lengths[req.slot]
             next_tok, self.kv.data = self._decode_steps[cls](
                 self.params, self.kv.data, jnp.asarray(tok),
                 jnp.asarray(slot_ids), jnp.asarray(lengths),
             )
-            next_tok = np.asarray(jax.block_until_ready(next_tok))
-        dt = time.perf_counter() - t0
-        self.kv.advance([r.slot for r in reqs])
-        for i, req in enumerate(reqs):
-            req.generated.append(int(next_tok[i]))
-            req.token_lat.append(dt)
-            done = len(req.generated) >= req.max_new
-            # the token just written sits at lengths[i]; the next one
-            # would land at lengths[i] + 1 — stop at the cache extent
-            if done or int(self.kv.lengths[req.slot]) + 1 >= self.max_seq:
-                self._finish(req)
+        with self._phases.phase("decode_wait"):
+            jax.block_until_ready(next_tok)
+        with self._phases.phase("fetch"):
+            next_tok = np.asarray(next_tok)
+        with self._phases.phase("retire"):
+            self.kv.advance([r.slot for r in reqs])
+            for i, req in enumerate(reqs):
+                req.generated.append(int(next_tok[i]))
+                done = len(req.generated) >= req.max_new
+                # the token just written sits at lengths[i]; the next one
+                # would land at lengths[i] + 1 — stop at the cache extent
+                if done or int(self.kv.lengths[req.slot]) + 1 >= self.max_seq:
+                    self._finish(req)
+        self._counts["decode_steps"] += 1
+        self._counts["decoded_rows"] += len(reqs)
 
     # -- the serve loop ------------------------------------------------------
 
@@ -470,11 +531,15 @@ class ServeEngine:
         tokens emitted.  A class whose decode step raises loses only that
         batch (evicted, ``crashed_steps`` counted); other classes and the
         loop itself keep serving."""
+        t0 = time.perf_counter()
         before = sum(len(r.generated) for r in self.requests.values())
-        self._expire_deadlines()
-        self._admit()
+        with self._phases.phase("expire"):
+            self._expire_deadlines()
+        with self._phases.phase("admit"):
+            touched = self._admit()
         by_cls = self._active_by_class()
         for cls in sorted(by_cls):
+            touched.extend(by_cls[cls])
             try:
                 self._decode_class(cls, by_cls[cls])
             except (KeyboardInterrupt, SystemExit):
@@ -491,6 +556,11 @@ class ServeEngine:
                     UserWarning,
                 )
         self.clock += 1
+        now = time.monotonic()
+        for req in touched:
+            req.token_times.extend([now] * (len(req.generated) - len(req.token_times)))
+        self._counts["steps"] += 1
+        self._step_s += time.perf_counter() - t0
         return sum(len(r.generated) for r in self.requests.values()) - before
 
     def run(self, max_steps: int = 100_000) -> None:
@@ -546,6 +616,21 @@ class ServeEngine:
         for cls, policy in self.policies.items():
             n = getattr(policy, "n_measured", 0)
             out[cls] = n - self._measured_at_warmup.get(cls, 0)
+        return out
+
+    def stats(self) -> Dict[str, float]:
+        """Cumulative counters of ``step()``: steps, decode steps (one per
+        class per step), decoded rows, prefills, prompt tokens and the
+        padded tokens prefilled for them, the steps' wall seconds
+        (``step_s``), host seconds per phase (``<phase>_s`` for
+        ``HOST_PHASES``) and seconds blocked on the device
+        (``prefill_wait_s``, ``decode_wait_s``).  ``host_s`` is ``step_s``
+        less the waits.  Subtract two readings for a window."""
+        out: Dict[str, float] = dict(self._counts)
+        out.update({f"{k}_s": v for k, v in self._phases.seconds.items()})
+        out["step_s"] = self._step_s
+        out["host_s"] = self._step_s - sum(
+            self._phases.seconds[k] for k in WAIT_PHASES)
         return out
 
     def health(self) -> Dict[str, int]:

@@ -24,6 +24,7 @@ from repro.serving import (
     ServeEngine,
     default_buckets,
 )
+from repro.serving.engine import HOST_PHASES
 
 TINY = ArchConfig(
     name="tiny-serve",
@@ -419,6 +420,22 @@ class TestServeDriver:
         assert engine.buckets.len_step == 8
         assert engine.health()["finished"] == 2
 
+    def test_reports_client_times_and_the_engines_counters(self, capsys):
+        from repro.launch import serve
+        from repro.serving.engine import WAIT_PHASES
+
+        engine = serve.main(_DRIVER_ARGS)
+        out = capsys.readouterr().out
+        for what in ("time to first token", "inter-token latency", "queue wait"):
+            assert f"[serve] {what}: p50 " in out
+        st = engine.stats()
+        assert (f"[serve] engine: {st['steps']} steps, {st['decode_steps']} decode steps, "
+                f"{st['decoded_rows']} rows decoded, 2 prefills") in out
+        per_step = next(line for line in out.splitlines()
+                        if line.startswith("[serve] engine ms per step: "))
+        for p in HOST_PHASES + WAIT_PHASES:
+            assert f" {p} " in per_step
+
     def test_crashed_step_exits_nonzero(self, monkeypatch):
         from repro.launch import serve
 
@@ -429,3 +446,67 @@ class TestServeDriver:
         with pytest.warns(UserWarning, match="crashed"):
             with pytest.raises(SystemExit, match="crashed"):
                 serve.main(_DRIVER_ARGS)
+
+
+class TestStepAccounting:
+    """Phase counters, spans and the client-side times of each token."""
+
+    def test_token_times_are_step_returns_on_the_submit_clock(self, tiny_params):
+        engine = make_engine(tiny_params, n_slots=2)
+        reqs = [engine.submit(p, max_new=3) for p in mixed_prompts([4, 6, 5])]
+        returns = []
+        while engine.queue or engine.kv.owner:
+            engine.step()
+            returns.append(time.monotonic())
+        for r in reqs:
+            assert r.state is RequestState.FINISHED
+            assert len(r.token_times) == len(r.generated) == 3
+            assert r.submit_time <= r.admit_time <= r.token_times[0]
+            assert r.token_times == sorted(r.token_times)
+            # each token is stamped at the return of the step that made it
+            assert all(any(0 <= t1 - t <= 0.5 for t1 in returns) for t in r.token_times)
+        # the third request waited for a slot: admitted a step later
+        assert reqs[2].admit_time > reqs[0].token_times[0]
+
+    def test_stats_count_the_steps_work_and_split_its_time(self, tiny_params):
+        engine = make_engine(tiny_params, n_slots=2)
+        assert engine.stats()["steps"] == 0
+        prompts = mixed_prompts([4, 9, 5])
+        for p in prompts:
+            engine.submit(p, max_new=3)
+        t0 = time.perf_counter()
+        engine.run()
+        wall = time.perf_counter() - t0
+        st = engine.stats()
+        assert st["steps"] == engine.clock
+        assert st["prefills"] == 3 and st["prompt_tokens"] == sum(len(p) for p in prompts)
+        assert st["prefill_tokens"] == sum(engine.buckets.bucket_len(len(p)) for p in prompts)
+        # every request's first token comes from prefill, the rest from decode
+        assert st["decoded_rows"] == 3 * 2
+        assert 0 < st["decode_steps"] <= st["steps"]
+        waits = st["prefill_wait_s"] + st["decode_wait_s"]
+        phases = sum(st[f"{p}_s"] for p in HOST_PHASES)
+        assert st["host_s"] == pytest.approx(st["step_s"] - waits)
+        assert phases + waits <= st["step_s"] + 1e-9
+        assert 0 < st["step_s"] <= wall
+
+    def test_phases_run_inside_profiler_spans(self, tiny_params, monkeypatch):
+        names = []
+
+        class Span:
+            def __init__(self, name):
+                names.append(name)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        engine = make_engine(tiny_params, n_slots=2)
+        engine.submit(mixed_prompts([4])[0], max_new=2)
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Span)
+        engine.step()
+        assert names == ["engine.expire", "engine.admit", "engine.prefill",
+                         "engine.prefill_wait", "engine.fetch", "engine.decode",
+                         "engine.decode_wait", "engine.fetch", "engine.retire"]

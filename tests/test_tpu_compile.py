@@ -11,6 +11,8 @@ process at a time may load the TPU library, and each test worker imports
 every test file.  Keep all such compiles in this one file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -130,3 +132,95 @@ def test_fused_attention_compiles(compile_for_tpu, case, dtype):
         [dtype, dtype, dtype, jnp.int32],
     )
     assert "tpu_custom_call" in text
+
+
+# -- dispatch scopes in the compiled v5e program ------------------------------
+
+_SCOPE = re.compile(r"dispatch\.[A-Z]+\.[0-9x]+(?:\.g\d+)?\.d\d\.[A-Za-z0-9_@x]+")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+)\s.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%([\w.\-]+) = .*?\s([\w\-]+)\(")
+
+
+def _innermost_scope(line: str):
+    m = re.search(r'op_name="([^"]*)"', line)
+    found = _SCOPE.findall(m.group(1)) if m else []
+    return found[-1] if found else None
+
+
+def _scoped_programs():
+    """Toy FCN and smollm-shaped training steps, each under a policy that
+    runs a Pallas NT arm, as the v5e compiles them."""
+    from repro.configs import smoke_config
+    from repro.launch.steps import (TrainStepConfig, make_fcn_train_step,
+                                    make_train_step, train_state_shapes)
+    from repro.models.fcn import FCNConfig, init_fcn
+    from repro.optim import adamw_init
+
+    fcn = FCNConfig("toy", 512, 512, (256, 256))
+    fcn_params = jax.eval_shape(lambda: init_fcn(jax.random.PRNGKey(0), fcn))
+    fcn_step = make_fcn_train_step(core.policy_from_spec("fixed:nt=PALLAS_TNN_FUSED"),
+                                   lambda s: jnp.float32(1e-3))
+    fcn_args = (fcn_params, jax.eval_shape(adamw_init, fcn_params),
+                jax.ShapeDtypeStruct((), jnp.int32),
+                {"x": jax.ShapeDtypeStruct((1024, 512), jnp.float32),
+                 "labels": jax.ShapeDtypeStruct((1024,), jnp.int32)})
+    lm = smoke_config("smollm-135m")
+    lm_step = make_train_step(lm, TrainStepConfig(),
+                              policy=core.policy_from_spec("fixed:nt=PALLAS_NT,attn=unfused"))
+    lm_args = (train_state_shapes(lm),
+               {"tokens": jax.ShapeDtypeStruct((2, 256), jnp.int32),
+                "labels": jax.ShapeDtypeStruct((2, 256), jnp.int32)})
+    return {"fcn": (fcn_step, fcn_args), "lm": (lm_step, lm_args)}
+
+
+@pytest.mark.parametrize("program", ["fcn", "lm"])
+def test_a_fusion_carries_its_products_dispatch_scope(one_chip, monkeypatch, program):
+    """The device trace names each op by its instruction's own ``op_name``:
+    on the v5e, every instruction the chip runs that multiplies matrices
+    (a product, a fusion holding one, a Pallas kernel) names the dispatch
+    scope of its product, so the trace attributes it to the arm that ran."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        fn, args = _scoped_programs()[program]
+        args = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), args)
+        text = jax.jit(fn).lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    product_scope, fusions, top = {}, [], []
+    comp, fused = None, set()
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)  # unindented: instructions are indented
+        if m:
+            comp = m.group(1)
+            continue
+        mi = _INSTRUCTION.match(line)
+        if not mi:
+            continue
+        name, opcode = mi.groups()
+        calls = re.search(r"calls=%([\w.\-]+)", line)
+        if opcode in ("dot", "convolution"):
+            product_scope.setdefault(comp, _innermost_scope(line))
+        if opcode == "fusion" and calls:
+            fused.add(calls.group(1))
+        top.append((comp, name, opcode, calls.group(1) if calls else None, line))
+    checked = pallas = 0
+    for comp, name, opcode, calls, line in top:
+        if comp in fused:
+            continue  # inside a fusion: the trace shows the fusion alone
+        if opcode in ("dot", "convolution"):
+            want = product_scope[comp]
+        elif opcode == "fusion" and calls in product_scope:
+            want = product_scope[calls]
+        elif opcode == "custom-call" and "tpu_custom_call" in line:
+            want, pallas = _innermost_scope(line), pallas + 1
+        else:
+            continue
+        assert want is not None, line[:300]
+        assert _innermost_scope(line) == want, line[:300]
+        checked += 1
+    assert checked >= 6 and pallas >= 1

@@ -31,13 +31,19 @@ width.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-from repro.core.engine import dispatch_attention
+from repro.core.engine import dispatch_attention, scope_name
+from repro.core.opkey import OpKey
+from repro.core.policy import Decision
+from repro.distributed.context import current_mesh
+from repro.kernels.attention_decode import PoolLayout, attention_decode_pool
 
 from .layers import Param, dense, init_dense, init_rmsnorm, rmsnorm
 from .rope import apply_rope
@@ -303,6 +309,8 @@ def attention_decode(
     their length (they used to, whenever ``pos`` under-described a mixed-
     length batch — the mask was shared across rows).
     """
+    if "slots" in cache:  # the serving engine's slot pool
+        return _decode_in_pool(p, x, cfg, cache, pos)
     B = x.shape[0]
     slots = cache["k"].shape[1]
     pos_b = jnp.broadcast_to(jnp.atleast_1d(jnp.asarray(pos, jnp.int32)), (B,))
@@ -332,4 +340,40 @@ def attention_decode(
         q2, k2, v2, lengths=lengths, softcap=cfg.softcap
     )
     out = out.reshape(B, 1, cfg.n_heads * cfg.d_head)
+    return dense(p["wo"], out), {"k": ck, "v": cv}
+
+
+def _decode_in_pool(p, x, cfg, cache, pos):
+    """One decode step read and written in place in the engine's slot
+    pool.  ``cache`` holds the whole pool leaves ``k``/``v``
+    ``(layers, slots+1, T, lanes)`` (``kernels/attention_decode``
+    layout), this layer's index ``layer`` and each row's slot ``slots``
+    (B,); ``pos`` (B,) is each row's new-token position.  The new K/V
+    land at ``(layer, slots[b], pos[b])`` (``pos % window`` in a ring)
+    and the kernel attends each row's ``min(pos + 1, window)`` valid
+    positions.  Returns the updated pool leaves."""
+    B = x.shape[0]
+    pos_b = jnp.broadcast_to(jnp.atleast_1d(jnp.asarray(pos, jnp.int32)), (B,))
+    q, k_new, v_new = _project_qkv(p, x, cfg, pos_b[:, None])
+    q = q[:, 0] * (cfg.d_head**-0.5)  # (B, kv, g, dh)
+    if cfg.window is not None:
+        write, valid = pos_b % cfg.window, jnp.minimum(pos_b + 1, cfg.window)
+    else:
+        write, valid = pos_b, pos_b + 1
+    layout, layer, slots = PoolLayout(cfg.n_kv, cfg.d_head), cache["layer"], cache["slots"]
+    ck = layout.write(cache["k"], k_new[:, 0], layer, slots, write)
+    cv = layout.write(cache["v"], v_new[:, 0], layer, slots, write)
+    positions = ck.shape[2]
+    key = OpKey("ATTN", cfg.group, positions, cfg.d_head,
+                jnp.dtype(q.dtype).itemsize, B * cfg.n_kv)
+    kernel = functools.partial(attention_decode_pool, layout=layout, softcap=cfg.softcap)
+    mesh = current_mesh()
+    if mesh is not None and mesh.size > 1:
+        # Mosaic kernels are not partitioned automatically: every device
+        # runs the kernel on the whole (replicated) operands
+        kernel = jax.shard_map(kernel, mesh=mesh, in_specs=P(), out_specs=P(),
+                               check_vma=False)
+    with jax.named_scope(scope_name(key, Decision("RAGGED_DECODE"))):
+        out = kernel(q, ck, cv, slots, valid, layer)
+    out = out.astype(q.dtype).reshape(B, 1, cfg.n_heads * cfg.d_head)
     return dense(p["wo"], out), {"k": ck, "v": cv}

@@ -25,7 +25,11 @@ from .layers import Param, gated_mlp, init_gated_mlp, init_rmsnorm, rmsnorm
 from .moe import init_moe, moe_layer
 from .ssm import init_ssm, init_ssm_cache, ssm_decode, ssm_layer
 
-__all__ = ["BlockCfg", "init_block", "apply_block", "decode_block", "init_block_cache"]
+__all__ = ["ATTN_MIXERS", "BlockCfg", "init_block", "apply_block", "decode_block",
+           "init_block_cache"]
+
+# mixers whose decode cache is attention K/V (the serving pool's kernel layout)
+ATTN_MIXERS = ("attn", "shared_attn")
 
 
 @dataclass(frozen=True)
@@ -130,7 +134,7 @@ def prefill_block(
     attention-only feature — the serving engine prefills SSM archs at
     exact lengths."""
     h = rmsnorm(p["ln1"], x)
-    if b.mixer in ("attn", "shared_attn"):
+    if b.mixer in ATTN_MIXERS:
         ap = p["attn"] if b.mixer == "attn" else shared["attn"]
         h, cache = attention(
             ap, h, _attn_cfg(b, mc), positions, prefix_len,
@@ -161,7 +165,7 @@ def prefill_block(
 
 
 def init_block_cache(b: BlockCfg, mc, batch: int, max_seq: int, dtype=jnp.bfloat16):
-    if b.mixer in ("attn", "shared_attn"):
+    if b.mixer in ATTN_MIXERS:
         return init_attn_cache(batch, _attn_cfg(b, mc), max_seq, dtype)
     return init_ssm_cache(batch, mc.ssm, dtype)
 

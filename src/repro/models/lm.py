@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from .blocks import (
+    ATTN_MIXERS,
     apply_block,
     decode_block,
     init_block,
@@ -240,27 +241,23 @@ def init_lm_cache(cfg, batch: int, max_seq: int, dtype=jnp.bfloat16,
     return {"segments": caches, "pos": pos}
 
 
-def _read_unit_cache(seg_cache, i):
-    """Dynamic per-unit slice of the stacked segment cache."""
-    return tuple(
-        jax.tree.map(lambda leaf: jax.lax.dynamic_index_in_dim(leaf, i, 0, False), sc)
-        for sc in seg_cache
+def _read_unit_cache(block_cache, i):
+    """Dynamic per-unit slice of one block's stacked cache."""
+    return jax.tree.map(
+        lambda leaf: jax.lax.dynamic_index_in_dim(leaf, i, 0, False), block_cache
     )
 
 
-def _write_unit_cache(seg_cache, new_unit, i):
-    """Write one unit's updated cache back into the stacked buffers.
+def _write_unit_cache(block_cache, new_unit, i):
+    """Write one unit's updated cache back into a block's stacked buffers.
 
     Chained dynamic-update-slices on a donated/carried buffer alias in
     place — the decode step holds ONE cache copy, not three (found via the
     dry-run memory proof; see EXPERIMENTS.md §Dry-run)."""
-    return tuple(
-        jax.tree.map(
-            lambda full, new: jax.lax.dynamic_update_index_in_dim(full, new, i, 0),
-            sc,
-            nu,
-        )
-        for sc, nu in zip(seg_cache, new_unit)
+    return jax.tree.map(
+        lambda full, new: jax.lax.dynamic_update_index_in_dim(full, new, i, 0),
+        block_cache,
+        new_unit,
     )
 
 
@@ -278,8 +275,15 @@ def lm_decode(
     form; see ``attention_decode``).  The stacked cache is carried whole
     through the layer scan and updated with dynamic slices, so XLA keeps
     it in place (while-loop carry aliasing).
+
+    With ``cache['slots']`` (B,) the attention leaves are the serving
+    engine's whole slot pool (``serving/kv_cache.py``): each attention
+    layer gets the pool leaves with its layer index and the rows' slots,
+    and reads and writes them in place (``attention._decode_in_pool``);
+    the other leaves (SSM state) hold the rows' own gathered state.
     """
     pos = cache["pos"]
+    slots = cache.get("slots")
     if cfg.input_mode == "frames":
         x = batch["frames"].astype(_dtype(cfg))
     else:
@@ -289,15 +293,24 @@ def lm_decode(
     for (count, blocks), slot_params, seg_cache in zip(
         cfg.segments, params["segments"], cache["segments"]
     ):
-        def unit(carry, xs, _blocks=blocks):
+        in_pool = tuple(
+            slots is not None and b.mixer in ATTN_MIXERS for b in blocks
+        )
+
+        def unit(carry, xs, _blocks=blocks, _in_pool=in_pool):
             h, seg = carry
             i, unit_params = xs
-            unit_cache = _read_unit_cache(seg, i)
-            new_unit = []
-            for b, bp, c in zip(_blocks, unit_params, unit_cache):
-                h, c2 = decode_block(bp, h, b, cfg, c, pos, shared)
-                new_unit.append(c2)
-            return (h, _write_unit_cache(seg, tuple(new_unit), i)), None
+            new_seg = []
+            for b, bp, c, pooled in zip(_blocks, unit_params, seg, _in_pool):
+                if pooled:
+                    h, c = decode_block(bp, h, b, cfg, dict(c, slots=slots, layer=i),
+                                        pos, shared)
+                else:
+                    h, cu = decode_block(bp, h, b, cfg, _read_unit_cache(c, i),
+                                         pos, shared)
+                    c = _write_unit_cache(c, cu, i)
+                new_seg.append(c)
+            return (h, tuple(new_seg)), None
 
         idx = jnp.arange(count, dtype=jnp.int32)
         if cfg.unroll_segments:

@@ -89,10 +89,12 @@ import numpy as np
 
 from repro.core.engine import dispatch_report
 from repro.core.policy import SelectionPolicy, use_policy
+from repro.distributed.context import use_mesh
+from repro.kernels.attention_decode import block_len, live_blocks
 from repro.models import lm
 
 from .buckets import BucketSpec, default_buckets
-from .kv_cache import PagedKVCache
+from .kv_cache import PagedKVCache, pool_extents, put_rows, take_rows
 
 __all__ = ["Request", "RequestState", "ServeEngine", "QueueFullError"]
 
@@ -285,8 +287,10 @@ class ServeEngine:
         # cumulative step counters (stats()), kept by the engine thread
         self._phases = _PhaseClock()
         self._counts: Dict[str, int] = dict.fromkeys(
-            ("steps", "decode_steps", "decoded_rows", "prefills",
-             "prompt_tokens", "prefill_tokens"), 0)
+            ("steps", "decode_steps", "decoded_rows", "decoded_rows_inplace",
+             "kv_blocks_read", "prefills", "prompt_tokens", "prefill_tokens"), 0)
+        # (layers, positions per slot) of each attention block in the pool
+        self._pool_extents = pool_extents(cfg, self.kv.data)
         self._step_s = 0.0
 
     # -- jitted steps (one trace per class x bucket shape) -----------------
@@ -298,21 +302,13 @@ class ServeEngine:
             # the scope wraps the traced body: selection happens at trace
             # time, so this class's policy governs every GEMM in the step
             with _policy_scope(policy):
-                gathered = jax.tree.map(
-                    lambda leaf: jnp.take(leaf, slot_ids, axis=1), segments
-                )
                 logits, new = lm.lm_decode(
                     params, cfg,
-                    {"segments": gathered, "pos": lengths},
+                    {"segments": take_rows(cfg, segments, slot_ids),
+                     "pos": lengths, "slots": slot_ids},
                     {"tokens": tok},
                 )
-                segments = jax.tree.map(
-                    lambda big, rows: big.at[:, slot_ids].set(
-                        rows.astype(big.dtype)
-                    ),
-                    segments,
-                    new["segments"],
-                )
+                segments = put_rows(cfg, segments, new["segments"], slot_ids)
                 next_tok = jnp.argmax(logits[:, -1, :vocab], axis=-1)
             return next_tok.astype(jnp.int32), segments
 
@@ -334,7 +330,7 @@ class ServeEngine:
         return prefill_step
 
     def _mesh_scope(self):
-        return self.mesh if self.mesh is not None else contextlib.nullcontext()
+        return use_mesh(self.mesh) if self.mesh is not None else contextlib.nullcontext()
 
     # -- request lifecycle -------------------------------------------------
 
@@ -522,6 +518,11 @@ class ServeEngine:
                     self._finish(req)
         self._counts["decode_steps"] += 1
         self._counts["decoded_rows"] += len(reqs)
+        if self._pool_extents:
+            self._counts["decoded_rows_inplace"] += len(reqs)
+            self._counts["kv_blocks_read"] += sum(
+                count * int(live_blocks(np.minimum(lengths + 1, T), block_len(T)).sum())
+                for count, T in self._pool_extents)
 
     # -- the serve loop ------------------------------------------------------
 
@@ -620,7 +621,10 @@ class ServeEngine:
 
     def stats(self) -> Dict[str, float]:
         """Cumulative counters of ``step()``: steps, decode steps (one per
-        class per step), decoded rows, prefills, prompt tokens and the
+        class per step), decoded rows, the rows decoded by the pool kernel
+        in place (``decoded_rows_inplace``) and the live (row, kv block)
+        pairs its grid fetched, summed over attention layers
+        (``kv_blocks_read``), prefills, prompt tokens and the
         padded tokens prefilled for them, the steps' wall seconds
         (``step_s``), host seconds per phase (``<phase>_s`` for
         ``HOST_PHASES``) and seconds blocked on the device

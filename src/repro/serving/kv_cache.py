@@ -1,14 +1,19 @@
 """Slot-based paged KV cache for the continuous-batching engine.
 
-One device-resident cache pytree (the ``segments`` half of
+One device-resident cache pytree (shaped like the ``segments`` half of
 ``models/lm.py::init_lm_cache``) holds ``n_slots + 1`` sequences: every
 leaf is ``(layers, n_slots + 1, ...)`` with the sequence axis at
-position 1.  A request is admitted by *allocating a slot* and scattering
-its (batch=1) prefill cache into that row; it is evicted by freeing the
-slot — no reshapes, no max-batch padding, and ragged sequence lengths
-coexist because every slot carries its own write position
-(``lengths``, the per-sequence ``pos`` vector ``attention_decode``
-consumes).
+position 1.  Attention leaves are stored in the decode kernel's layout
+(``kernels/attention_decode.PoolLayout``: ``(layers, n_slots + 1, T,
+lanes)``), so a decode step reads and writes them in place by slot
+id and never copies them; other leaves (SSM state) keep their own shape
+and are gathered and scattered by row (``take_rows`` / ``put_rows``).  A
+request is admitted by *allocating a slot* and moving its (batch=1)
+prefill cache into that row, in the pool's layout; it is evicted by
+freeing the slot — no reshapes, no max-batch padding, and ragged
+sequence lengths coexist because every slot carries its own write
+position (``lengths``, the per-sequence ``pos`` vector
+``attention_decode`` consumes).
 
 The extra row — ``null_slot`` — is scratch: decode steps run at bucketed
 batch sizes, and the padding rows of a partially-filled bucket all point
@@ -25,15 +30,76 @@ them.
 from __future__ import annotations
 
 import threading
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.attention_decode import PoolLayout
 from repro.models import lm
+from repro.models.blocks import ATTN_MIXERS
 
-__all__ = ["PagedKVCache"]
+__all__ = ["PagedKVCache", "init_pool", "pool_extents", "put_rows", "take_rows"]
+
+
+def _per_block(cfg, attn: Callable, other: Callable, *trees):
+    """Map ``attn`` over the attention blocks' caches of the segment
+    trees and ``other`` over the rest, block by block."""
+    return [
+        tuple(
+            (attn if b.mixer in ATTN_MIXERS else other)(*caches)
+            for b, *caches in zip(blocks, *segs)
+        )
+        for (_, blocks), *segs in zip(cfg.segments, *trees)
+    ]
+
+
+def init_pool(cfg, n_rows: int, max_seq: int, dtype=jnp.bfloat16):
+    """Zero pool of ``n_rows`` sequences: attention leaves in the decode
+    kernel's layout, other leaves as ``lm.init_lm_cache`` makes them."""
+    layout = PoolLayout(cfg.n_kv, cfg.d_head)
+    shapes = jax.eval_shape(
+        lambda: lm.init_lm_cache(cfg, n_rows, max_seq, dtype))["segments"]
+    return _per_block(
+        cfg,
+        lambda c: jax.tree.map(lambda s: jnp.zeros(layout.shape(s.shape), s.dtype), c),
+        lambda c: jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), c),
+        shapes,
+    )
+
+
+def pool_extents(cfg, pool):
+    """``(layers, positions per slot)`` of each attention block's leaves."""
+    return [
+        (c["k"].shape[0], c["k"].shape[2])
+        for (_, blocks), seg in zip(cfg.segments, pool)
+        for b, c in zip(blocks, seg)
+        if b.mixer in ATTN_MIXERS
+    ]
+
+
+def take_rows(cfg, pool, slot_ids):
+    """The decode step's view of the pool for rows ``slot_ids``: the
+    attention leaves whole (the kernel indexes them by slot), the other
+    leaves' rows gathered."""
+    return _per_block(
+        cfg, lambda c: c,
+        lambda c: jax.tree.map(lambda leaf: jnp.take(leaf, slot_ids, axis=1), c),
+        pool,
+    )
+
+
+def put_rows(cfg, pool, rows, slot_ids):
+    """Inverse of ``take_rows`` after a decode step: the attention leaves
+    as the step left them (written in place), the others' rows scattered
+    back."""
+    return _per_block(
+        cfg, lambda big, new: new,
+        lambda big, new: jax.tree.map(
+            lambda b, r: b.at[:, slot_ids].set(r.astype(b.dtype)), big, new),
+        pool, rows,
+    )
 
 
 class PagedKVCache:
@@ -46,23 +112,31 @@ class PagedKVCache:
         self.n_slots = int(n_slots)
         self.max_seq = int(max_seq)
         self.null_slot = self.n_slots  # scratch row for bucket padding
-        self.data = lm.init_lm_cache(
-            cfg, self.n_slots + 1, max_seq, dtype=dtype
-        )["segments"]
+        self.data = init_pool(cfg, self.n_slots + 1, max_seq, dtype)
         # slot bookkeeping is shared with the engine's admission path;
         # allocate/free must be atomic under concurrent submitters
         self._lock = threading.Lock()
         self._free: List[int] = list(range(self.n_slots))  # guarded-by: _lock
         self.lengths = np.zeros(self.n_slots + 1, np.int32)
         self.owner: Dict[int, Any] = {}  # slot -> request id; guarded-by: _lock
-        self._insert = jax.jit(self._insert_impl, donate_argnums=(0,))
+        layout = PoolLayout(cfg.n_kv, cfg.d_head)
+        self._insert = jax.jit(
+            lambda big, rows, slot: _per_block(
+                cfg,
+                lambda b, r: self._insert_leaves(b, r, slot, layout.pack),
+                lambda b, r: self._insert_leaves(b, r, slot, lambda x: x),
+                big, rows,
+            ),
+            donate_argnums=(0,),
+        )
 
     @staticmethod
-    def _insert_impl(big, rows, slot):
-        """Scatter a batch=1 cache pytree into row ``slot`` (axis 1)."""
+    def _insert_leaves(big, rows, slot, to_pool):
+        """Land a batch=1 cache in row ``slot`` (axis 1), in the pool's
+        layout."""
         return jax.tree.map(
             lambda b, r: jax.lax.dynamic_update_slice_in_dim(
-                b, r.astype(b.dtype), slot, axis=1
+                b, to_pool(r).astype(b.dtype), slot, axis=1
             ),
             big,
             rows,

@@ -14,6 +14,7 @@ import pytest
 
 from repro.configs.arch import ArchConfig, BlockCfg
 from repro.core.policy import AutotunePolicy, FixedPolicy
+from repro.kernels import attention_decode
 from repro.launch.common import parse_mesh, resolve_mesh_and_policy
 from repro.models import lm
 from repro.serving import (
@@ -182,6 +183,33 @@ class TestServeEngine:
         for req, prompt in zip(reqs, prompts):
             expect = reference_generate(TINY_WINDOWED, params, prompt, 5)
             assert req.generated == expect, f"rid={req.rid}"
+
+    @pytest.mark.parametrize("cfg", [TINY, TINY_WINDOWED], ids=["global", "windowed"])
+    def test_decode_step_changes_only_the_rows_new_positions(self, cfg):
+        """After one decode step the pool is bit-identical outside the
+        batch's slots, and inside them outside each row's write position
+        (``pos % window`` in a ring)."""
+        params = lm.init_lm(jax.random.PRNGKey(5), cfg)
+        engine = make_engine(params, cfg=cfg)
+        keys = iter(jax.random.split(jax.random.PRNGKey(6), 16))
+        pool = jax.tree.map(lambda leaf: jax.random.normal(next(keys), leaf.shape, leaf.dtype),
+                            engine.kv.data)
+        before = jax.tree.map(np.asarray, pool)
+        null = engine.kv.null_slot
+        slot_ids = np.array([2, 0, null, null], np.int32)
+        lengths = np.array([5, 17, 0, 0], np.int32)
+        _, after = engine._decode_steps["interactive"](
+            params, pool, jnp.zeros((4, 1), jnp.int32), jnp.asarray(slot_ids),
+            jnp.asarray(lengths))
+        window = cfg.segments[0][1][0].window
+        for old, new in zip(jax.tree.leaves(before), jax.tree.leaves(after)):
+            new = np.asarray(new)  # (layers, slots+1, positions, lanes)
+            for s in (1, 3):
+                np.testing.assert_array_equal(new[:, s], old[:, s])
+            for s, n in zip(slot_ids[:2], lengths[:2]):
+                keep = np.arange(old.shape[2]) != (n % window if window else n)
+                np.testing.assert_array_equal(new[:, s, keep], old[:, s, keep])
+                assert not np.array_equal(new[:, s, ~keep], old[:, s, ~keep])
 
     def test_admit_evict_midstream_reuses_slot(self, tiny_params):
         """Evicting an active request mid-stream frees its slot for the
@@ -468,7 +496,8 @@ class TestStepAccounting:
         # the third request waited for a slot: admitted a step later
         assert reqs[2].admit_time > reqs[0].token_times[0]
 
-    def test_stats_count_the_steps_work_and_split_its_time(self, tiny_params):
+    def test_stats_count_the_steps_work_and_split_its_time(self, tiny_params, monkeypatch):
+        monkeypatch.setattr(attention_decode, "DECODE_BLOCK", 8)  # 8 positions a grid step
         engine = make_engine(tiny_params, n_slots=2)
         assert engine.stats()["steps"] == 0
         prompts = mixed_prompts([4, 9, 5])
@@ -483,6 +512,11 @@ class TestStepAccounting:
         assert st["prefill_tokens"] == sum(engine.buckets.bucket_len(len(p)) for p in prompts)
         # every request's first token comes from prefill, the rest from decode
         assert st["decoded_rows"] == 3 * 2
+        # buckets (1, 2) on 2 slots: no padding rows, every row in the pool
+        # kernel; row at position p reads cdiv(p + 1, 8) blocks of each layer
+        assert st["decoded_rows_inplace"] == st["decoded_rows"]
+        blocks = sum(-(-(len(p) + k + 1) // 8) for p in prompts for k in range(2))
+        assert st["kv_blocks_read"] == 2 * blocks  # TINY's two attention layers
         assert 0 < st["decode_steps"] <= st["steps"]
         waits = st["prefill_wait_s"] + st["decode_wait_s"]
         phases = sum(st[f"{p}_s"] for p in HOST_PHASES)

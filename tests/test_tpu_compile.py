@@ -224,3 +224,106 @@ def test_a_fusion_carries_its_products_dispatch_scope(one_chip, monkeypatch, pro
         assert _innermost_scope(line) == want, line[:300]
         checked += 1
     assert checked >= 6 and pallas >= 1
+
+
+# -- the serving engine's decode step at serve-batch widths -------------------
+
+
+def _output_shape(line: str):
+    m = re.search(r"= [a-z0-9]+\[([0-9,]*)\]", line)
+    return tuple(int(d) for d in m.group(1).split(",") if d) if m else None
+
+
+def test_decode_step_reads_the_pool_in_place(compile_for_tpu, one_chip):
+    """smollm-135m, 64 slots of 2048, batch bucket 64: the decode step
+    holds the pool kernel, and no instruction materialises the whole
+    pool leaf, one layer's (slots x max_seq) slab of it, or the batch's
+    rows of it.  What has the pool's shape is the donated parameter, its
+    while-loop carry, and the in-place scatter of the new tokens."""
+    from repro.configs import get_config
+    from repro.models import lm
+    from repro.serving import ServeEngine, kv_cache
+
+    cfg = get_config("smollm-135m")
+    slots, max_seq, batch = 64, 2048, 64
+    engine = object.__new__(ServeEngine)  # the step needs cfg alone, not a pool
+    engine.cfg = cfg
+    step = engine._make_decode_step(core.policy_from_spec("model"))
+    params = jax.eval_shape(lambda: lm.init_lm(jax.random.PRNGKey(0), cfg))
+    pool = jax.eval_shape(lambda: kv_cache.init_pool(cfg, slots + 1, max_seq))
+
+    def sharded(tree):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree)
+
+    ints = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+            for s in ((batch, 1), (batch,), (batch,))]
+    text = jax.jit(step, donate_argnums=(1,)).lower(
+        sharded(params), sharded(pool), *ints).compile().as_text()
+    assert "tpu_custom_call" in text
+
+    leaf = jax.tree.leaves(pool)[0].shape  # (layers, slots+1, max_seq, lanes)
+    big = {leaf, leaf[1:], (leaf[0], batch) + leaf[2:], (batch,) + leaf[2:]}
+    roots, fused, lines, comp = {}, set(), [], None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        mi = _INSTRUCTION.match(line)
+        if not mi:
+            continue
+        calls = re.search(r"calls=%([\w.\-]+)", line)
+        if mi.group(2) == "fusion" and calls:
+            fused.add(calls.group(1))
+        if line.lstrip().startswith("ROOT"):
+            roots[comp] = mi.group(2)
+        lines.append((comp, mi.group(2), calls.group(1) if calls else None, line))
+    seen = 0
+    for comp, opcode, calls, line in lines:
+        if comp in fused or _output_shape(line) not in big:
+            continue  # a fusion's inside runs as the fusion
+        seen += 1
+        in_place = opcode == "fusion" and roots.get(calls) == "scatter"
+        assert opcode in ("parameter", "get-tuple-element", "tuple", "while") or in_place, (
+            line[:300])
+    assert seen >= 4  # the parameters and the scatters were found
+
+
+def test_decode_step_compiles_on_a_2x2_mesh(topo, monkeypatch):
+    """Serving on a four-chip mesh (``launch/serve.py --mesh 2x2``, the
+    parameters sharded): the pool kernel runs whole on every chip, under
+    a ``shard_map``, since Mosaic kernels are not partitioned
+    automatically."""
+    import numpy as np
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from repro.configs import smoke_config
+    from repro.distributed import param_specs
+    from repro.distributed.context import use_mesh
+    from repro.models import lm
+    from repro.serving import ServeEngine, kv_cache
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    cfg = smoke_config("smollm-135m")
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    engine = object.__new__(ServeEngine)
+    engine.cfg = cfg
+    step = engine._make_decode_step(core.policy_from_spec("model", distributed=True))
+    params = jax.eval_shape(lambda: lm.init_lm(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(
+        lambda s, spec: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=NamedSharding(mesh, spec)),
+        params, param_specs(params, mesh))
+    whole = NamedSharding(mesh, PartitionSpec())
+    pool = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=whole),
+                        jax.eval_shape(lambda: kv_cache.init_pool(cfg, 9, 256)))
+    ints = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=whole) for s in ((8, 1), (8,), (8,))]
+    try:
+        with use_mesh(mesh):
+            text = jax.jit(step, donate_argnums=(1,)).lower(params, pool, *ints).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert "tpu_custom_call" in text

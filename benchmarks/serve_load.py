@@ -81,7 +81,7 @@ def run_load(args) -> dict:
     t0 = time.perf_counter()
     pending = list(trace)
     n_steps = 0
-    while pending or engine.queue or engine.kv.owner:
+    while pending or engine.has_work():
         while pending and pending[0][0] <= engine.clock:
             _, prompt, max_new, cls = pending.pop(0)
             engine.submit(prompt, max_new=max_new, cls=cls)

@@ -180,7 +180,9 @@ def _engine_main(args, parser):
     steps = max(st["steps"], 1)
     print(f"[serve] engine: {st['steps']} steps, {st['decode_steps']} decode steps, "
           f"{st['decoded_rows']} rows decoded, {st['prefills']} prefills of "
-          f"{st['prompt_tokens']} prompt tokens padded to {st['prefill_tokens']}")
+          f"{st['prompt_tokens']} prompt tokens padded to {st['prefill_tokens']}; "
+          f"{st['overlapped_steps']} decode launches overlapped a fetch, "
+          f"{st['discarded_rows']} tokens discarded")
     print("[serve] engine ms per step: "
           + ", ".join(f"{p} {st[p + '_s'] / steps * 1e3:.3f}"
                       for p in HOST_PHASES + WAIT_PHASES)

@@ -8,8 +8,10 @@ machinery, replacing the fixed-batch prefill/decode demo.
 Lifecycle of a request (``Request``/``RequestState``):
 
   QUEUED            submitted, waiting FCFS for a slot + admission budget
-  ACTIVE            admitted: prefilled into a ``PagedKVCache`` slot, decoding
-  FINISHED          emitted ``max_new`` tokens (or hit the cache extent)
+  ACTIVE            admitted: prefilled into a ``PagedKVCache`` slot, decoding,
+                    or out of the batch with its last token still in flight
+  FINISHED          every one of its ``max_new`` tokens (or as many as the
+                    cache extent holds) has reached the host
   EVICTED           cancelled mid-stream (or its decode step crashed);
                     its slot is freed and reused
   DEADLINE_EXCEEDED its wall-clock deadline passed; evicted between
@@ -23,21 +25,38 @@ Robustness (the fault-tolerance layer, ``core/faults.py``):
   * **Backpressure** — the admission queue is bounded (``max_queue``);
     ``submit`` raises ``QueueFullError`` instead of growing without
     bound (callers shed load explicitly).
-  * **Crash containment** — a decode/prefill step that raises evicts
-    only the requests in that batch and counts a ``crashed_steps``;
-    the serve loop keeps going.  Candidate-level failures never get
-    this far: dispatch degrades down the fallback chain inside the
-    trace (``core/engine.run_decision``), so a fault-injected Pallas
-    kernel quarantines itself and the step completes on the XLA
-    reference — chaos-tested in ``tests/test_faults.py``.
+  * **Crash containment** — a decode/prefill step that raises, at its
+    launch or when its tokens are fetched, evicts only the requests in
+    that batch and counts a ``crashed_steps``; the serve loop keeps
+    going.  Candidate-level failures never get this far: dispatch
+    degrades down the fallback chain inside the trace
+    (``core/engine.run_decision``), so a fault-injected Pallas kernel
+    quarantines itself and the step completes on the XLA reference —
+    chaos-tested in ``tests/test_faults.py``.
 
-Between decode steps the scheduler **admits** queued requests (FCFS,
-gated by free slots and a max-tokens admission budget) and **evicts**
-finished/cancelled ones — the decode batch is recomposed every step, so
-short requests never hold the batch hostage for long ones (continuous
+Each step **admits** queued requests (FCFS, gated by free slots and a
+max-tokens admission budget) and recomposes the decode batch, so short
+requests never hold the batch hostage for long ones (continuous
 batching).  Ragged lengths coexist in one cache because every slot
 carries its own write position (``attention_decode``'s per-sequence
 ``pos`` vector + validity mask).
+
+**Overlap.**  A decode's input tokens stay on the device
+(``PagedKVCache.tokens``), and nothing else about a row's next step
+depends on a token's value: its length is known on the host, and it
+stops after a count of tokens (``max_new`` or the cache extent), never
+on a token.  So ``step()`` launches this step's decode for every live
+row *before* it blocks on the previous step's tokens, and the chip has
+the next program queued while the host fetches, retires, admits and
+returns to its caller.  A request's decode tokens reach the host (and
+``generated``) one step after their decode is launched; its prefill's
+first token arrives in the step that admits it.  A row leaves the batch,
+returning its slot and budget, once its last token has been *launched*;
+the request turns ``FINISHED`` once that token has *arrived*.  A token
+in flight for a request evicted or expired meanwhile is discarded and
+counted (``discarded_rows``); ``run()`` drains the step in flight.
+The host work of a step walks only live requests (queued, decoding or
+awaiting tokens), never every request the engine has served.
 
 Every request carries a *class* (e.g. ``interactive`` / ``bulk``) mapped
 to its own ``SelectionPolicy``.  Each class's steps are traced inside
@@ -47,19 +66,22 @@ policies concurrently, and ``class_reports()`` renders one
 ``dispatch_report`` per class.
 
 Observability: each phase of ``step()`` runs inside a profiler span
-(``jax.profiler.TraceAnnotation``, nearly free while no trace is taken):
-``engine.expire``, ``engine.admit`` holding one ``engine.prefill`` (pad,
-launch, KV insert), ``engine.prefill_wait`` (blocked until the first
-token is computed) and ``engine.fetch`` (its copy to the host) per
-admitted request, then per class ``engine.decode`` (build inputs,
-launch), ``engine.decode_wait`` (blocked until the next tokens are
-computed), ``engine.fetch`` and ``engine.retire``.  A wait span ends when
-the program it waits for has ended, so the benchmark's trace reduction
-anchors the host clock on it.  ``stats()`` returns cumulative counters of
-the same phases, with the seconds blocked on the device kept apart from
-host seconds; ``launch/serve.py`` prints them per step.  Each request
-records its admission time and the time each of its tokens reached the
-host (the step's return), on the clock of ``submit_time``.
+(``jax.profiler.TraceAnnotation``, nearly free while no trace is taken),
+in this order: ``engine.expire``; ``engine.admit`` holding one
+``engine.prefill`` (pad, launch, KV insert) per admitted request; per
+class ``engine.decode`` (build inputs, launch, drop the rows whose last
+token is launched); ``engine.decode_wait`` (blocked until the previous
+step's decode tokens are computed); ``engine.prefill_wait`` (blocked
+until this step's first tokens are computed); ``engine.fetch`` (their
+copy to the host, started at launch) and ``engine.retire`` (tokens to
+their requests, finished requests out).  A wait span ends when the
+program it waits for has ended (or at once, when it already had), so
+the benchmark's trace reduction anchors the host clock on it.
+``stats()`` returns cumulative counters of the same phases, with the
+seconds blocked on the device kept apart from host seconds;
+``launch/serve.py`` prints them per step.  Each request records its
+admission time and the time each of its tokens reached the host (the
+step's return), on the clock of ``submit_time``.
 
 Decode shapes are bucketed (``buckets.BucketSpec``): the active batch
 rounds up to a small bucket set (padding rows target the cache's null
@@ -133,6 +155,8 @@ class Request:
     state: RequestState = RequestState.QUEUED
     slot: Optional[int] = None
     generated: List[int] = dataclasses.field(default_factory=list)
+    # tokens launched on the device; ``generated`` trails by those in flight
+    launched: int = 0
     submit_step: int = -1
     admit_step: int = -1
     finish_step: int = -1
@@ -164,6 +188,19 @@ def _policy_scope(policy: Optional[SelectionPolicy]):
 # host phases of one engine step, and the device waits kept apart from them
 HOST_PHASES = ("expire", "admit", "prefill", "decode", "fetch", "retire")
 WAIT_PHASES = ("prefill_wait", "decode_wait")
+
+
+@dataclasses.dataclass
+class _InFlight:
+    """Tokens launched on the device and not yet on the host: one class's
+    decode batch (``tok`` has a row per request, then padding rows), or
+    one admitted request's first token."""
+
+    what: str  # names the step in a crash warning
+    reqs: List[Request]
+    tok: Any
+    decode: bool = False
+    kv_blocks: int = 0  # (row, kv block) pairs the decode's kernel reads
 
 
 class _PhaseClock:
@@ -271,7 +308,12 @@ class ServeEngine:
         # submit from request threads while the engine loop admits
         self._lock = threading.Lock()
         self.queue: deque = deque()  # guarded-by: _lock
+        # every request ever submitted, by id (evict, health); the step
+        # walks only the live ones: queued, decoding or awaiting tokens
         self.requests: Dict[int, Request] = {}
+        self._live: Dict[int, Request] = {}  # guarded-by: _lock
+        self._batch: Dict[int, Request] = {}  # decoding rows, by slot
+        self._inflight: List[_InFlight] = []  # decodes launched, unfetched
         self.clock = 0  # engine iterations (the virtual timeline)
         self._next_rid = 0
         self._reserved = 0  # guarded-by: _lock
@@ -288,7 +330,8 @@ class ServeEngine:
         self._phases = _PhaseClock()
         self._counts: Dict[str, int] = dict.fromkeys(
             ("steps", "decode_steps", "decoded_rows", "decoded_rows_inplace",
-             "kv_blocks_read", "prefills", "prompt_tokens", "prefill_tokens"), 0)
+             "kv_blocks_read", "prefills", "prompt_tokens", "prefill_tokens",
+             "overlapped_steps", "discarded_rows"), 0)
         # (layers, positions per slot) of each attention block in the pool
         self._pool_extents = pool_extents(cfg, self.kv.data)
         self._step_s = 0.0
@@ -298,7 +341,7 @@ class ServeEngine:
     def _make_decode_step(self, policy: Optional[SelectionPolicy]):
         cfg, vocab = self.cfg, self.cfg.vocab
 
-        def decode_step(params, segments, tok, slot_ids, lengths):
+        def decode_step(params, segments, tokens, slot_ids, lengths):
             # the scope wraps the traced body: selection happens at trace
             # time, so this class's policy governs every GEMM in the step
             with _policy_scope(policy):
@@ -306,7 +349,7 @@ class ServeEngine:
                     params, cfg,
                     {"segments": take_rows(cfg, segments, slot_ids),
                      "pos": lengths, "slots": slot_ids},
-                    {"tokens": tok},
+                    {"tokens": tokens[slot_ids][:, None]},
                 )
                 segments = put_rows(cfg, segments, new["segments"], slot_ids)
                 next_tok = jnp.argmax(logits[:, -1, :vocab], axis=-1)
@@ -381,52 +424,75 @@ class ServeEngine:
         self._next_rid += 1
         self.requests[req.rid] = req
         with self._lock:
+            self._live[req.rid] = req
             self.queue.append(req)
         return req
 
+    def _leave_batch(self, req: Request) -> None:
+        """Take a decoding row out of the batch, returning its slot and
+        budget reservation.  A token already launched for it is in
+        flight; the slot's next occupant is ordered after it on the
+        device, since the pool and token vector thread every program."""
+        del self._batch[req.slot]
+        self.kv.free(req.slot)
+        with self._lock:
+            self._reserved -= req.reserve
+
     def _release(self, req: Request, state: RequestState) -> None:
         """Move a live request to a terminal state, returning its
-        resources: an ACTIVE request's slot + budget reservation, a
+        resources: a decoding request's slot + budget reservation, a
         QUEUED one's queue position."""
-        if req.state is RequestState.ACTIVE:
-            self.kv.free(req.slot)
-            with self._lock:
-                self._reserved -= req.reserve
-        elif req.state is RequestState.QUEUED:
-            with self._lock:
+        with self._lock:
+            if req.state is RequestState.QUEUED:
                 self.queue.remove(req)
+            del self._live[req.rid]
+        if self._batch.get(req.slot) is req:
+            self._leave_batch(req)
         req.state = state
         req.finish_step = self.clock
 
     def evict(self, rid: int) -> Request:
         """Cancel a request mid-stream.  An ACTIVE request's slot returns
-        to the pool immediately (reused by the next admission); a QUEUED
-        one just leaves the queue."""
+        to the pool immediately (reused by the next admission) and a
+        token in flight for it is discarded; a QUEUED one just leaves the
+        queue."""
         req = self.requests[rid]
         if req.state in TERMINAL_STATES:
             return req
         self._release(req, RequestState.EVICTED)
         return req
 
-    def _finish(self, req: Request) -> None:
-        self._release(req, RequestState.FINISHED)
-
     def _expire_deadlines(self) -> List[Request]:
         """Evict every live request past its wall-clock deadline — runs
         between decode steps, so an overdue request costs at most one
         step's latency past its budget, never a whole generation."""
         now = time.monotonic()
-        expired = []
-        for req in self.requests.values():
-            if req.state not in TERMINAL_STATES and req.overdue(now):
-                self._release(req, RequestState.DEADLINE_EXCEEDED)
-                self.deadline_evictions += 1
-                expired.append(req)
-        return expired
+        with self._lock:
+            overdue = [r for r in self._live.values()
+                       if r.deadline_s is not None and r.overdue(now)]
+        for req in overdue:
+            self._release(req, RequestState.DEADLINE_EXCEEDED)
+            self.deadline_evictions += 1
+        return overdue
 
-    def _admit(self) -> List[Request]:
+    def _crashed(self, what: str, reqs: List[Request], e: Exception) -> None:
+        """Contain a step that raised: its live requests die, the engine
+        lives (candidate failures degrade inside the trace and never
+        reach here — this catches whole-step failures)."""
+        self.crashed_steps += 1
+        dead = [r for r in reqs if r.state is RequestState.ACTIVE]
+        for req in dead:
+            self._release(req, RequestState.EVICTED)
+        warnings.warn(
+            f"{what} crashed ({type(e).__name__}: {e}); {len(dead)} "
+            "request(s) evicted, engine continues",
+            UserWarning,
+        )
+
+    def _admit(self) -> List[_InFlight]:
         """FCFS admission: pop the queue head while a slot is free and the
-        max-tokens budget holds, prefill it, land its cache in the slot."""
+        max-tokens budget holds, launch its prefill and land its cache and
+        first token in the slot.  Returns the first tokens, in flight."""
         admitted = []
         while self.queue:
             with self._lock:
@@ -444,130 +510,160 @@ class ServeEngine:
             req.state = RequestState.ACTIVE
             req.admit_step = self.clock
             req.admit_time = time.monotonic()
+            self._batch[slot] = req
             P = req.prompt_len
             Lb = P if self.exact_prefill else self.buckets.bucket_len(P)
+            what = f"prefill for request {req.rid} (class {req.cls!r})"
             try:
                 with self._phases.phase("prefill"), self._mesh_scope():
                     padded = np.zeros((1, Lb), np.int32)
                     padded[0, :P] = req.tokens
                     tok, cache = self._prefill_steps[req.cls](
-                        self.params, jnp.asarray(padded), jnp.int32(P)
+                        self.params, padded, np.int32(P)
                     )
-                    self.kv.insert(cache, slot, P)
-                with self._phases.phase("prefill_wait"):
-                    jax.block_until_ready(tok)
-                with self._phases.phase("fetch"):
-                    tok = int(np.asarray(tok)[0])
+                    self.kv.insert(cache, slot, P, tok)
+                    tok.copy_to_host_async()
             except (KeyboardInterrupt, SystemExit):
                 raise
             except Exception as e:
-                # contain the blast radius: this request dies, the engine
-                # lives (candidate failures degrade inside the trace and
-                # never reach here — this catches whole-step failures)
-                self.crashed_steps += 1
-                self._release(req, RequestState.EVICTED)
-                warnings.warn(
-                    f"prefill for request {req.rid} (class {req.cls!r}) "
-                    f"crashed ({type(e).__name__}: {e}); request evicted",
-                    UserWarning,
-                )
+                self._crashed(what, [req], e)
                 continue
-            req.generated.append(tok)
+            req.launched = 1
+            if req.launched >= req.max_new:
+                self._leave_batch(req)
             self._counts["prefills"] += 1
             self._counts["prompt_tokens"] += P
             self._counts["prefill_tokens"] += Lb
-            admitted.append(req)
+            admitted.append(_InFlight(what, [req], tok))
         return admitted
 
-    def _active_by_class(self) -> Dict[str, List[Request]]:
-        by_cls: Dict[str, List[Request]] = {}
-        for req in self.requests.values():
-            if req.state is RequestState.ACTIVE:
-                by_cls.setdefault(req.cls, []).append(req)
-        for reqs in by_cls.values():
-            reqs.sort(key=lambda r: r.slot)
-        return by_cls
-
-    def _decode_class(self, cls: str, reqs: List[Request]) -> None:
-        """One bucketed decode step for one class's active requests."""
+    def _decode_class(self, cls: str, reqs: List[Request]) -> _InFlight:
+        """Launch one bucketed decode step for one class's decoding rows.
+        Rows whose last token it launches leave the batch at once."""
         with self._phases.phase("decode"), self._mesh_scope():
-            Bb = self.buckets.bucket_batch(len(reqs))
-            slot_ids = np.full(Bb, self.kv.null_slot, np.int32)
-            tok = np.zeros((Bb, 1), np.int32)
-            lengths = np.zeros(Bb, np.int32)
-            for i, req in enumerate(reqs):
-                slot_ids[i] = req.slot
-                tok[i, 0] = req.generated[-1]
-                lengths[i] = self.kv.lengths[req.slot]
-            next_tok, self.kv.data = self._decode_steps[cls](
-                self.params, self.kv.data, jnp.asarray(tok),
-                jnp.asarray(slot_ids), jnp.asarray(lengths),
+            n = len(reqs)
+            slot_ids = np.full(self.buckets.bucket_batch(n), self.kv.null_slot, np.int32)
+            slot_ids[:n] = [r.slot for r in reqs]
+            lengths = np.zeros_like(slot_ids)
+            lengths[:n] = self.kv.lengths[slot_ids[:n]]
+            slots_dev, lengths_dev = jax.device_put((slot_ids, lengths))
+            tok, self.kv.data = self._decode_steps[cls](
+                self.params, self.kv.data, self.kv.tokens, slots_dev, lengths_dev,
             )
-        with self._phases.phase("decode_wait"):
-            jax.block_until_ready(next_tok)
+            self.kv.put_tokens(slots_dev, tok)
+            tok.copy_to_host_async()
+            if self._inflight:
+                self._counts["overlapped_steps"] += 1
+            self.kv.advance(slot_ids[:n])
+            for req in reqs:
+                req.launched += 1
+                # the next input would land at lengths[slot] — stop at
+                # the cache extent as at ``max_new``
+                if (req.launched >= req.max_new
+                        or int(self.kv.lengths[req.slot]) + 1 >= self.max_seq):
+                    self._leave_batch(req)
+        blocks = sum(
+            count * int(live_blocks(np.minimum(lengths + 1, T), block_len(T)).sum())
+            for count, T in self._pool_extents)
+        return _InFlight(f"decode step for class {cls!r}", reqs, tok, True, blocks)
+
+    def _collect(self, launched: List[_InFlight]) -> Dict[int, Request]:
+        """Block on tokens in flight (the previous step's decodes, then
+        this step's prefills), copy them to the host and hand each to its
+        request; a request whose last token arrived finishes.  A batch
+        whose tokens fail to arrive is evicted as a crashed step.
+        Returns the requests that received tokens."""
+        ready = []
+        for wait, decode in (("decode_wait", True), ("prefill_wait", False)):
+            with self._phases.phase(wait):
+                for f in launched:
+                    if (f.decode is decode
+                            and self._guard(f, jax.block_until_ready) is not None):
+                        ready.append(f)
         with self._phases.phase("fetch"):
-            next_tok = np.asarray(next_tok)
+            fetched = [(f, toks) for f in ready
+                       if (toks := self._guard(f, np.asarray)) is not None]
+        got: Dict[int, Request] = {}
         with self._phases.phase("retire"):
-            self.kv.advance([r.slot for r in reqs])
-            for i, req in enumerate(reqs):
-                req.generated.append(int(next_tok[i]))
-                done = len(req.generated) >= req.max_new
-                # the token just written sits at lengths[i]; the next one
-                # would land at lengths[i] + 1 — stop at the cache extent
-                if done or int(self.kv.lengths[req.slot]) + 1 >= self.max_seq:
-                    self._finish(req)
-        self._counts["decode_steps"] += 1
-        self._counts["decoded_rows"] += len(reqs)
-        if self._pool_extents:
-            self._counts["decoded_rows_inplace"] += len(reqs)
-            self._counts["kv_blocks_read"] += sum(
-                count * int(live_blocks(np.minimum(lengths + 1, T), block_len(T)).sum())
-                for count, T in self._pool_extents)
+            for f, toks in fetched:
+                if f.decode:
+                    n = len(f.reqs)
+                    self._counts["decode_steps"] += 1
+                    self._counts["decoded_rows"] += n
+                    if self._pool_extents:
+                        self._counts["decoded_rows_inplace"] += n
+                        self._counts["kv_blocks_read"] += f.kv_blocks
+                for req, t in zip(f.reqs, toks.tolist()):
+                    if req.state is not RequestState.ACTIVE:
+                        self._counts["discarded_rows"] += 1
+                        continue
+                    req.generated.append(t)
+                    got[req.rid] = req
+                    if (self._batch.get(req.slot) is not req
+                            and len(req.generated) == req.launched):
+                        self._release(req, RequestState.FINISHED)
+        return got
+
+    def _guard(self, f: _InFlight, fn):
+        """``fn(f.tok)``, or None with ``f``'s batch evicted if it raises."""
+        try:
+            return fn(f.tok)
+        except (KeyboardInterrupt, SystemExit):
+            raise
+        except Exception as e:
+            self._crashed(f.what, f.reqs, e)
+            return None
 
     # -- the serve loop ------------------------------------------------------
 
+    def has_work(self) -> bool:
+        """True while a request is queued, decoding or awaiting tokens, or
+        a decode's tokens are still in flight."""
+        return bool(self._live or self._inflight)
+
     def step(self) -> int:
-        """One engine iteration: expire overdue deadlines, admit, then one
-        decode step per class with active requests.  Returns the number of
-        tokens emitted.  A class whose decode step raises loses only that
-        batch (evicted, ``crashed_steps`` counted); other classes and the
-        loop itself keep serving."""
+        """One engine iteration: expire overdue deadlines, admit (launching
+        the prefills), launch one decode step per class with decoding rows,
+        then fetch the previous step's decode tokens and this step's first
+        tokens.  Returns the number of tokens that reached the host.  A
+        batch whose step raises, at launch or at the fetch, loses only its
+        requests (evicted, ``crashed_steps`` counted); other classes and
+        the loop itself keep serving."""
         t0 = time.perf_counter()
-        before = sum(len(r.generated) for r in self.requests.values())
         with self._phases.phase("expire"):
             self._expire_deadlines()
         with self._phases.phase("admit"):
-            touched = self._admit()
-        by_cls = self._active_by_class()
+            launched = self._admit()
+        by_cls: Dict[str, List[Request]] = {}
+        for req in self._batch.values():
+            by_cls.setdefault(req.cls, []).append(req)
+        decodes = []
         for cls in sorted(by_cls):
-            touched.extend(by_cls[cls])
             try:
-                self._decode_class(cls, by_cls[cls])
+                decodes.append(self._decode_class(cls, by_cls[cls]))
             except (KeyboardInterrupt, SystemExit):
                 raise
             except Exception as e:
-                self.crashed_steps += 1
-                for req in by_cls[cls]:
-                    if req.state is RequestState.ACTIVE:
-                        self._release(req, RequestState.EVICTED)
-                warnings.warn(
-                    f"decode step for class {cls!r} crashed "
-                    f"({type(e).__name__}: {e}); {len(by_cls[cls])} "
-                    "request(s) evicted, engine continues",
-                    UserWarning,
-                )
+                self._crashed(f"decode step for class {cls!r}", by_cls[cls], e)
+        launched += self._inflight
+        self._inflight = decodes
+        got = self._collect(launched)
         self.clock += 1
         now = time.monotonic()
-        for req in touched:
-            req.token_times.extend([now] * (len(req.generated) - len(req.token_times)))
+        emitted = 0
+        for req in got.values():
+            new = len(req.generated) - len(req.token_times)
+            req.token_times.extend([now] * new)
+            emitted += new
         self._counts["steps"] += 1
         self._step_s += time.perf_counter() - t0
-        return sum(len(r.generated) for r in self.requests.values()) - before
+        return emitted
 
     def run(self, max_steps: int = 100_000) -> None:
-        """Drain: step until queue and slots are empty."""
+        """Drain: step until no request is queued, decoding or awaiting
+        its tokens, and no decode is in flight."""
         for _ in range(max_steps):
-            if not self.queue and not self.kv.owner:
+            if not self.has_work():
                 return
             self.step()
         raise RuntimeError(f"engine did not drain within {max_steps} steps")
@@ -590,18 +686,16 @@ class ServeEngine:
                     slot_ids = jnp.full(
                         (Bb,), self.kv.null_slot, jnp.int32
                     )
-                    tok = jnp.zeros((Bb, 1), jnp.int32)
                     lengths = jnp.zeros((Bb,), jnp.int32)
-                    _, self.kv.data = self._decode_steps[cls](
-                        self.params, self.kv.data, tok, slot_ids, lengths
+                    tok, self.kv.data = self._decode_steps[cls](
+                        self.params, self.kv.data, self.kv.tokens, slot_ids, lengths
                     )
+                    self.kv.put_tokens(slot_ids, tok)
                     n_shapes += 1
                 if not self.exact_prefill:
                     for Lb in self.buckets.prefill_lens:
                         self._prefill_steps[cls](
-                            self.params,
-                            jnp.zeros((1, Lb), jnp.int32),
-                            jnp.int32(Lb),
+                            self.params, np.zeros((1, Lb), np.int32), np.int32(Lb)
                         )
                         n_shapes += 1
         self.kv.lengths[:] = 0  # warmup scribbled on the null row only
@@ -629,7 +723,11 @@ class ServeEngine:
         (``step_s``), host seconds per phase (``<phase>_s`` for
         ``HOST_PHASES``) and seconds blocked on the device
         (``prefill_wait_s``, ``decode_wait_s``).  ``host_s`` is ``step_s``
-        less the waits.  Subtract two readings for a window."""
+        less the waits.  A decode step and its rows count when its tokens
+        reach the host; ``overlapped_steps`` counts the decode launches
+        made while the previous step's decode tokens were still in flight,
+        ``discarded_rows`` the tokens that arrived for requests evicted or
+        expired meanwhile.  Subtract two readings for a window."""
         out: Dict[str, float] = dict(self._counts)
         out.update({f"{k}_s": v for k, v in self._phases.seconds.items()})
         out["step_s"] = self._step_s
@@ -672,12 +770,8 @@ class ServeEngine:
         return out
 
     def __repr__(self):
-        active = sum(
-            1 for r in self.requests.values()
-            if r.state is RequestState.ACTIVE
-        )
         return (
             f"ServeEngine(arch={self.cfg.name!r}, slots={self.kv.n_slots}, "
-            f"queued={len(self.queue)}, active={active}, "
+            f"queued={len(self.queue)}, active={len(self._batch)}, "
             f"classes={sorted(self.policies)})"
         )

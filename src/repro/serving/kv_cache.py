@@ -21,14 +21,22 @@ at it, so their writes land on trash instead of a live sequence (scatter
 order over duplicate indices is undefined; duplicates of a row nobody
 reads are harmless).
 
-Device work (insert) is jitted with the big cache donated, so admission
-updates the pool in place.  Slot bookkeeping (free list, lengths,
-owners) is host-side numpy — it changes between jit calls, never inside
-them.
+Beside the pool, ``tokens`` holds the last token of every row (the null
+row included) on the device: a prefill's first token lands there with
+its cache, each decode's tokens are scattered back (``put_tokens``), and
+the next decode gathers its input from it by slot id.  So the host never
+uploads a token, and can launch a decode before the previous one's
+tokens have reached it.
+
+Device work (insert, put_tokens) is jitted with the big cache and the
+token vector donated, so both are updated in place.  Slot bookkeeping
+(free list, lengths, owners) is host-side numpy — it changes between jit
+calls, never inside them.
 """
 
 from __future__ import annotations
 
+import functools
 import threading
 from typing import Any, Callable, Dict, List, Optional
 
@@ -102,6 +110,11 @@ def put_rows(cfg, pool, rows, slot_ids):
     )
 
 
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _put_tokens(tokens, slot_ids, tok):
+    return tokens.at[slot_ids].set(tok)
+
+
 class PagedKVCache:
     """Fixed pool of ``n_slots`` sequence slots + 1 null scratch row."""
 
@@ -113,6 +126,7 @@ class PagedKVCache:
         self.max_seq = int(max_seq)
         self.null_slot = self.n_slots  # scratch row for bucket padding
         self.data = init_pool(cfg, self.n_slots + 1, max_seq, dtype)
+        self.tokens = jnp.zeros(self.n_slots + 1, jnp.int32)
         # slot bookkeeping is shared with the engine's admission path;
         # allocate/free must be atomic under concurrent submitters
         self._lock = threading.Lock()
@@ -121,13 +135,13 @@ class PagedKVCache:
         self.owner: Dict[int, Any] = {}  # slot -> request id; guarded-by: _lock
         layout = PoolLayout(cfg.n_kv, cfg.d_head)
         self._insert = jax.jit(
-            lambda big, rows, slot: _per_block(
+            lambda big, tokens, rows, token, slot: (_per_block(
                 cfg,
                 lambda b, r: self._insert_leaves(b, r, slot, layout.pack),
                 lambda b, r: self._insert_leaves(b, r, slot, lambda x: x),
                 big, rows,
-            ),
-            donate_argnums=(0,),
+            ), jax.lax.dynamic_update_slice_in_dim(tokens, token, slot, axis=0)),
+            donate_argnums=(0, 1),
         )
 
     @staticmethod
@@ -173,20 +187,25 @@ class PagedKVCache:
             self._free.append(slot)
         self.lengths[slot] = 0
 
-    def insert(self, prefill_cache: Dict[str, Any], slot: int, length: int):
+    def insert(self, prefill_cache: Dict[str, Any], slot: int, length: int, token):
         """Land a request's prefill cache (batch=1 pytree from
-        ``lm_prefill``) in its slot and record its true length."""
+        ``lm_prefill``) in its slot with its first token (a ``(1,)``
+        device array), and record its true length."""
         if slot not in self.owner:
             raise KeyError(f"slot {slot} is not allocated")
-        self.data = self._insert(
-            self.data, prefill_cache["segments"], jnp.int32(slot)
+        self.data, self.tokens = self._insert(
+            self.data, self.tokens, prefill_cache["segments"], token, np.int32(slot)
         )
         self.lengths[slot] = int(length)
 
+    def put_tokens(self, slot_ids, tok) -> None:
+        """A decode step's tokens become its rows' next inputs."""
+        self.tokens = _put_tokens(self.tokens, slot_ids, tok)
+
     def advance(self, slots) -> None:
-        """One decode step happened for ``slots``: their lengths grew."""
-        for s in slots:
-            self.lengths[s] += 1
+        """One decode step was launched for ``slots`` (distinct): their
+        lengths grew."""
+        self.lengths[np.asarray(slots, np.intp)] += 1
 
     def __repr__(self):
         return (

@@ -144,11 +144,14 @@ class TestPagedKVCache:
             tiny_params, TINY, {"tokens": jnp.zeros((1, 4), jnp.int32)},
             max_seq=16, cache_dtype=jnp.float32,
         )
+        token = jnp.asarray([9], jnp.int32)
         with pytest.raises(KeyError):
-            kv.insert(cache, 0, 4)
+            kv.insert(cache, 0, 4, token)
         slot = kv.allocate("r0")
-        kv.insert(cache, slot, 4)
+        kv.insert(cache, slot, 4, token)
         assert kv.lengths[slot] == 4
+        # the first token lands in the slot's entry of the token vector
+        assert np.asarray(kv.tokens).tolist() == [9 if s == slot else 0 for s in range(3)]
         kv.advance([slot])
         assert kv.lengths[slot] == 5
 
@@ -199,7 +202,7 @@ class TestServeEngine:
         slot_ids = np.array([2, 0, null, null], np.int32)
         lengths = np.array([5, 17, 0, 0], np.int32)
         _, after = engine._decode_steps["interactive"](
-            params, pool, jnp.zeros((4, 1), jnp.int32), jnp.asarray(slot_ids),
+            params, pool, engine.kv.tokens, jnp.asarray(slot_ids),
             jnp.asarray(lengths))
         window = cfg.segments[0][1][0].window
         for old, new in zip(jax.tree.leaves(before), jax.tree.leaves(after)):
@@ -483,7 +486,7 @@ class TestStepAccounting:
         engine = make_engine(tiny_params, n_slots=2)
         reqs = [engine.submit(p, max_new=3) for p in mixed_prompts([4, 6, 5])]
         returns = []
-        while engine.queue or engine.kv.owner:
+        while engine.has_work():
             engine.step()
             returns.append(time.monotonic())
         for r in reqs:
@@ -541,6 +544,154 @@ class TestStepAccounting:
         engine.submit(mixed_prompts([4])[0], max_new=2)
         monkeypatch.setattr(jax.profiler, "TraceAnnotation", Span)
         engine.step()
+        # the decode launches before any wait; the wait for the previous
+        # step's decode tokens (none yet) comes before this step's first
+        # tokens, then one fetch and one retire for all of them
         assert names == ["engine.expire", "engine.admit", "engine.prefill",
-                         "engine.prefill_wait", "engine.fetch", "engine.decode",
-                         "engine.decode_wait", "engine.fetch", "engine.retire"]
+                         "engine.decode", "engine.decode_wait", "engine.prefill_wait",
+                         "engine.fetch", "engine.retire"]
+
+
+class TestOverlappedStep:
+    """Each step launches its decode before it fetches the previous one's
+    tokens: tokens arrive a step late, rows leave the batch by count, and
+    the host walks only live requests."""
+
+    @pytest.mark.parametrize("how", ["evict", "deadline"])
+    def test_token_in_flight_for_a_dropped_request_is_discarded(self, tiny_params, how):
+        """A request evicted, or expired, while its decode is in flight:
+        the token is discarded and counted, its slot goes to the queue
+        head, and every token served stays exact."""
+        engine = make_engine(tiny_params, n_slots=2)
+        prompts = mixed_prompts([4, 6, 5])
+        deadline = 0.2 if how == "deadline" else None
+        r0 = engine.submit(prompts[0], max_new=8, deadline_s=deadline)
+        r1, r2 = (engine.submit(p, max_new=8) for p in prompts[1:])
+        engine.step()
+        assert (r0.launched, len(r0.generated)) == (2, 1)  # one token in flight
+        victim_slot = r0.slot
+        if how == "evict":
+            engine.evict(r0.rid)
+        else:
+            time.sleep(deadline)
+        engine.step()
+        expected_state = {"evict": RequestState.EVICTED,
+                          "deadline": RequestState.DEADLINE_EXCEEDED}[how]
+        assert r0.state is expected_state and len(r0.generated) == 1
+        assert engine.stats()["discarded_rows"] == 1
+        assert r2.state is RequestState.ACTIVE and r2.slot == victim_slot
+        engine.run()
+        assert r0.generated == reference_generate(TINY, tiny_params, prompts[0], 1)
+        for req, prompt in ((r1, prompts[1]), (r2, prompts[2])):
+            assert req.state is RequestState.FINISHED
+            assert req.generated == reference_generate(TINY, tiny_params, prompt, 8)
+        assert engine.stats()["discarded_rows"] == 1
+
+    def test_finished_means_every_token_arrived(self, tiny_params):
+        """``FINISHED`` comes with all ``max_new`` tokens and their times,
+        one step after the last decode's launch; ``run()`` drains that
+        step, and a one-token request is done with its prefill."""
+        engine = make_engine(tiny_params, n_slots=2)
+        lens, news = [4, 7, 5, 9, 3], [1, 2, 5, 3, 4]
+        prompts = mixed_prompts(lens)
+        reqs = [engine.submit(p, max_new=n) for p, n in zip(prompts, news)]
+        seen_finished = set()
+        for _ in range(4):
+            engine.step()
+            for r in reqs:
+                if r.state is RequestState.FINISHED:
+                    assert len(r.generated) == len(r.token_times) == r.max_new
+                    seen_finished.add(r.rid)
+                else:
+                    assert len(r.generated) < r.max_new
+        assert seen_finished  # some finished while others decoded
+        # a row's slot goes back once its last token is launched, before
+        # that token has arrived: the engine still has work without slots
+        while engine.kv.owner or engine.queue:
+            engine.step()
+        assert engine.has_work() and any(r.state is RequestState.ACTIVE for r in reqs)
+        engine.run()
+        assert not engine.has_work()
+        for r, p in zip(reqs, prompts):
+            assert r.state is RequestState.FINISHED
+            assert len(r.generated) == len(r.token_times) == r.max_new
+            assert r.generated == reference_generate(TINY, tiny_params, p, r.max_new)
+
+    def test_step_walks_only_live_requests(self, tiny_params, monkeypatch):
+        """After many finished requests, a step never walks the map of
+        every request, and checks a deadline only of the live ones."""
+        engine = make_engine(tiny_params, n_slots=2)
+        for p in mixed_prompts([3, 5, 4, 6, 7, 3, 5, 4, 6, 2, 8, 5]):
+            engine.submit(p, max_new=3, deadline_s=60.0)
+        engine.run()
+
+        class NoWalk(dict):
+            def _walk(self, *a):
+                raise AssertionError("the step walked every request")
+
+            __iter__ = values = items = keys = _walk
+
+        engine.requests = NoWalk(engine.requests)
+        checked = []
+        real_overdue = engine.requests[0].overdue.__func__
+        monkeypatch.setattr(type(engine.requests[0]), "overdue",
+                            lambda self, now: checked.append(self.rid) or real_overdue(self, now))
+        late = [engine.submit(p, max_new=4, deadline_s=60.0) for p in mixed_prompts([4, 6, 5])]
+        while engine.has_work():
+            live = sum(r.state not in (RequestState.FINISHED,) for r in late)
+            checked.clear()
+            engine.step()
+            assert len(checked) == live <= len(late)
+        assert all(r.state is RequestState.FINISHED for r in late)
+
+    def test_overlapped_steps_count_every_decode_but_the_first(self, tiny_params):
+        """In a steady run of one class every decode launch but the first
+        finds the previous decode's tokens still in flight."""
+        engine = make_engine(tiny_params, n_slots=2)
+        for p in mixed_prompts([4, 6, 5, 7]):
+            engine.submit(p, max_new=5)
+        engine.run()
+        st = engine.stats()
+        assert st["decode_steps"] == 2 * 4  # two waves of four decodes each
+        assert st["overlapped_steps"] == st["decode_steps"] - 1
+        assert st["discarded_rows"] == 0
+
+    @pytest.mark.parametrize("where", ["launch", "fetch"])
+    def test_crashed_decode_evicts_only_its_batch(self, tiny_params, monkeypatch, where):
+        """One class's decode raising at its launch, or when its tokens
+        are fetched, evicts that class's requests; the other class
+        finishes exact, and the evicted rows' tokens still in flight are
+        discarded."""
+        engine = make_engine(tiny_params, n_slots=4)
+        prompts = mixed_prompts([4, 6, 5, 7])
+        classes = ["interactive", "bulk"] * 2
+        reqs = [engine.submit(p, max_new=6, cls=c) for p, c in zip(prompts, classes)]
+        real_step, real_block = engine._decode_steps["bulk"], jax.block_until_ready
+        calls, lost = [], []
+
+        def flaky(*args):
+            calls.append(1)
+            if len(calls) == 2 and where == "launch":
+                raise RuntimeError("decode step lost")
+            tok, segments = real_step(*args)
+            if len(calls) == 2:
+                lost.append(tok)
+            return tok, segments
+
+        def block(x):
+            if any(x is t for t in lost):
+                raise RuntimeError("tokens lost")
+            return real_block(x)
+
+        engine._decode_steps["bulk"] = flaky
+        monkeypatch.setattr(jax, "block_until_ready", block)
+        with pytest.warns(UserWarning, match="decode step for class 'bulk' crashed"):
+            engine.run()
+        assert engine.crashed_steps == 1
+        assert engine.stats()["discarded_rows"] == 2
+        for req, prompt, cls in zip(reqs, prompts, classes):
+            if cls == "bulk":
+                assert req.state is RequestState.EVICTED and len(req.generated) < 6
+            else:
+                assert req.state is RequestState.FINISHED
+                assert req.generated == reference_generate(TINY, tiny_params, prompt, 6)

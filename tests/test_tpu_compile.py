@@ -257,7 +257,7 @@ def test_decode_step_reads_the_pool_in_place(compile_for_tpu, one_chip):
             lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree)
 
     ints = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
-            for s in ((batch, 1), (batch,), (batch,))]
+            for s in ((slots + 1,), (batch,), (batch,))]
     text = jax.jit(step, donate_argnums=(1,)).lower(
         sharded(params), sharded(pool), *ints).compile().as_text()
     assert "tpu_custom_call" in text
@@ -320,7 +320,7 @@ def test_decode_step_compiles_on_a_2x2_mesh(topo, monkeypatch):
     whole = NamedSharding(mesh, PartitionSpec())
     pool = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=whole),
                         jax.eval_shape(lambda: kv_cache.init_pool(cfg, 9, 256)))
-    ints = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=whole) for s in ((8, 1), (8,), (8,))]
+    ints = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=whole) for s in ((9,), (8,), (8,))]
     try:
         with use_mesh(mesh):
             text = jax.jit(step, donate_argnums=(1,)).lower(params, pool, *ints).compile().as_text()
